@@ -27,15 +27,18 @@ from repro.analysis.diagnostics import (AnalysisContext, Diagnostic, error,
 from repro.api.registry import EXECUTORS
 from repro.kernels.daq_dequant import dequant_spmm, dequant_spmm_batched
 from repro.kernels.gather_aggregate import (block_spmm, block_spmm_batched,
-                                            padded_feature_dim)
+                                            padded_feature_dim,
+                                            spmm_vmem_limit)
 from repro.runtime.bsp import KERNEL_KINDS
 
-#: ~16 MB of VMEM per TPU core (see the Pallas guide's memory-space table);
-#: one grid step's resident operands must fit with headroom to spare.
-VMEM_BUDGET_BYTES = 16 * 1024 * 1024
-#: SMEM is "small" (scalar memory); the scalar-prefetched [VB, M] column
-#: table must stay tiny.  Heuristic budget — the exact size is per-chip.
-SMEM_BUDGET_BYTES = 64 * 1024
+#: VMEM of one TPU v5e core. Each SpMM launch asks for the scoped-VMEM
+#: limit its block shapes need (``spmm_vmem_limit``); past this it cannot
+#: get it.
+VMEM_BUDGET_BYTES = 128 * 1024 * 1024
+#: SMEM of one TPU v5e core (1 MiB: a compile for a described v5e refuses
+#: scalar-prefetched tables past it). Each SpMM launch prefetches its
+#: [VB, M] column and mask tables there, M padded to 128 lanes.
+SMEM_BUDGET_BYTES = 1024 * 1024
 
 _KERNELS = {
     "block_spmm": block_spmm,
@@ -276,28 +279,26 @@ def check_vmem_budget(ctx: AnalysisContext) -> Iterable[Diagnostic]:
         f_tile = min(128, spec.f)
         tiles = m * b * b * 4
         panel = spec.src_rows * f_tile * spec.wire_dtype.itemsize
-        acc = b * f_tile * 4
-        vmem = tiles + panel + acc
-        if spec.quant:
-            vmem += 2 * spec.src_rows * 4     # scale + min rows
+        vmem = spmm_vmem_limit(m, b, spec.src_rows, f_tile, spec.wire_dtype,
+                               row_params=spec.quant)
         if vmem > VMEM_BUDGET_BYTES:
             out.append(warning(
-                cid, f"{spec.label}: one grid step holds ~{vmem / 2**20:.1f}"
-                     f" MiB in VMEM (tiles {tiles / 2**20:.1f} + source "
-                     f"panel {panel / 2**20:.1f} + acc) against the "
-                     f"~{VMEM_BUDGET_BYTES // 2**20} MiB/core budget — the "
-                     f"launch will spill or fail to lower on hardware",
+                cid, f"{spec.label}: one grid step asks for "
+                     f"~{vmem / 2**20:.1f} MiB of VMEM (tiles "
+                     f"{tiles / 2**20:.1f} + source panel "
+                     f"{panel / 2**20:.1f}, double-buffered) against the "
+                     f"{VMEM_BUDGET_BYTES // 2**20} MiB of a v5e core — "
+                     f"the launch will fail to lower on hardware",
                 layer="kernel", subject=spec.label,
                 fix_hint="shard the graph further (smaller per-partition "
                          "source tables) or tile the source panel"))
-        if spec.batch is not None:
-            smem = vb * m * 4   # scalar-prefetched [VB, M] i32 column table
-            if smem > SMEM_BUDGET_BYTES:
-                out.append(warning(
-                    cid, f"{spec.label}: the scalar-prefetched column "
-                         f"table is {smem / 1024:.0f} KiB against a "
-                         f"~{SMEM_BUDGET_BYTES // 1024} KiB SMEM budget",
-                    layer="kernel", subject=spec.label,
-                    fix_hint="the ELL width M is blowing up — repartition "
-                             "or densify the shard"))
+        smem = 2 * vb * (-(-m // 128) * 128) * 4   # cols + mask tables
+        if smem > SMEM_BUDGET_BYTES:
+            out.append(warning(
+                cid, f"{spec.label}: the scalar-prefetched column and mask "
+                     f"tables take {smem / 1024:.0f} KiB against the "
+                     f"{SMEM_BUDGET_BYTES // 1024} KiB of SMEM on a v5e core",
+                layer="kernel", subject=spec.label,
+                fix_hint="the ELL width M is blowing up — repartition "
+                         "or densify the shard"))
     return out

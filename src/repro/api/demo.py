@@ -38,6 +38,9 @@ def main(argv=None) -> int:
 
     from repro.api import Engine, traces
     from repro.gnn import datasets, models
+    from repro.runtime import compile_cache
+
+    compile_cache.enable()
 
     graph = datasets.load(args.dataset, scale=args.scale, seed=0)
     params, loss = models.train_node_classifier(

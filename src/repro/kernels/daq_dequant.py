@@ -25,13 +25,37 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.gather_aggregate import BLOCK
+from repro.kernels.gather_aggregate import BLOCK, spmm_vmem_limit
 
 
-def _dequant_kernel(codes_ref, scales_ref, mins_ref, out_ref):
+def _as_f32(codes):
+    """Unsigned codes -> f32. Mosaic has no unsigned-to-float cast, so go
+    through int32: exact for every code below 2**31, the same values as a
+    direct cast (DAQ codes are at most 16 bits, the halo wire's 8)."""
+    return codes.astype(jnp.int32).astype(jnp.float32)
+
+
+def _row_params(scales, mins):
+    """Pack per-row (scale, min) as one lane-major [..., 2, V] table.
+
+    Mosaic refuses 1-D row-parameter blocks (their tiled layout disagrees
+    with XLA's) and would pad a [V, 1] column to 128 lanes in VMEM; a
+    [2, V] table is dense, sliced on the lane axis at 128-aligned offsets,
+    and transposed in-kernel to per-row columns (see ``_row_columns``)."""
+    return jnp.stack([scales, mins], axis=-2)
+
+
+def _row_columns(sm):
+    """[2, R] lane-major (scale, min) slice -> two [R, 1] columns (exact)."""
+    t = jnp.transpose(sm)
+    return t[:, 0:1], t[:, 1:2]
+
+
+def _dequant_kernel(codes_ref, sm_ref, out_ref):
     """One (v_tile, f_tile) VMEM tile: out = codes * scale[row] + min[row]."""
-    codes = codes_ref[...].astype(jnp.float32)
-    out_ref[...] = codes * scales_ref[...][:, None] + mins_ref[...][:, None]
+    codes = _as_f32(codes_ref[...])
+    sc, mn = _row_columns(sm_ref[...])
+    out_ref[...] = codes * sc + mn
 
 
 @functools.partial(jax.jit, static_argnames=("v_tile", "f_tile", "interpret"))
@@ -49,33 +73,32 @@ def dequant(codes: jnp.ndarray, scales: jnp.ndarray, mins: jnp.ndarray, *,
         grid=grid,
         in_specs=[
             pl.BlockSpec((v_tile, f_tile), lambda i, j: (i, j)),
-            pl.BlockSpec((v_tile,), lambda i, j: (i,)),
-            pl.BlockSpec((v_tile,), lambda i, j: (i,)),
+            pl.BlockSpec((2, v_tile), lambda i, j: (0, i)),
         ],
         out_specs=pl.BlockSpec((v_tile, f_tile), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((v, f), jnp.float32),
         interpret=interpret,
-    )(codes, scales, mins)
+    )(codes, _row_params(scales, mins))
 
 
-def _dequant_spmm_kernel(cols_ref, mask_ref, blocks_ref, codes_ref,
-                         scales_ref, mins_ref, out_ref, *, m: int,
-                         block: int):
-    """One (row-block, feature-tile) grid step: the [B, TF] source panel is
-    dequantized in VMEM right before each MXU matmul, so the dense feature
-    table never materializes in HBM."""
-    acc = jnp.zeros_like(out_ref)
+def _dequant_spmm_kernel(cols_ref, mask_ref, blocks_ref, codes_ref, sm_ref,
+                         out_ref, *, m: int, block: int):
+    """One (row-block, feature-tile[, batch]) grid step: the [B, TF] source
+    panel is dequantized in VMEM right before each MXU matmul, so the dense
+    feature table never materializes in HBM. ``cols_ref``/``mask_ref`` are
+    the whole scalar-prefetched [VB, M] tables (SMEM), as in
+    ``gather_aggregate._spmm_kernel``."""
+    i = pl.program_id(0)
+    acc = jnp.zeros(out_ref.shape, jnp.float32)
 
     def body(k, acc):
         tile = blocks_ref[k]                                    # [B, B]
-        col = cols_ref[k]
-        msk = mask_ref[k]
-        codes = codes_ref[pl.dslice(col * block, block), :]     # [B, TF]
-        sc = scales_ref[pl.dslice(col * block, block)]          # [B]
-        mn = mins_ref[pl.dslice(col * block, block)]            # [B]
-        panel = codes.astype(jnp.float32) * sc[:, None] + mn[:, None]
-        return acc + msk * jnp.dot(tile, panel,
-                                   preferred_element_type=jnp.float32)
+        start = pl.multiple_of(cols_ref[i, k] * block, block)
+        codes = codes_ref[pl.dslice(start, block), :]           # [B, TF]
+        sc, mn = _row_columns(sm_ref[:, pl.dslice(start, block)])  # [B, 1]
+        panel = _as_f32(codes) * sc + mn
+        return acc + mask_ref[i, k] * jnp.dot(
+            tile, panel, preferred_element_type=jnp.float32)
 
     acc = jax.lax.fori_loop(0, m, body, acc)
     out_ref[...] = acc
@@ -104,45 +127,26 @@ def dequant_spmm(blocks: jnp.ndarray, block_cols: jnp.ndarray,
     assert f % f_tile == 0
     grid = (vb, f // f_tile)
     kernel = functools.partial(_dequant_spmm_kernel, m=m, block=block)
-    return pl.pallas_call(
-        kernel,
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,           # block_cols, block_mask: SMEM
         grid=grid,
         in_specs=[
-            pl.BlockSpec((None, m), lambda i, j: (i, 0)),
-            pl.BlockSpec((None, m), lambda i, j: (i, 0)),
-            pl.BlockSpec((None, m, block, block), lambda i, j: (i, 0, 0, 0)),
-            pl.BlockSpec((v, f_tile), lambda i, j: (0, j)),   # codes panel
-            pl.BlockSpec((v,), lambda i, j: (0,)),
-            pl.BlockSpec((v,), lambda i, j: (0,)),
+            pl.BlockSpec((None, m, block, block),
+                         lambda i, j, *_: (i, 0, 0, 0)),
+            pl.BlockSpec((v, f_tile), lambda i, j, *_: (0, j)),  # codes panel
+            pl.BlockSpec((2, v), lambda i, j, *_: (0, 0)),       # scale, min
         ],
-        out_specs=pl.BlockSpec((block, f_tile), lambda i, j: (i, j)),
+        out_specs=pl.BlockSpec((block, f_tile), lambda i, j, *_: (i, j)),
+    )
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((vb * block, f), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=spmm_vmem_limit(m, block, v, f_tile, codes.dtype,
+                                             row_params=True)),
         interpret=interpret,
-    )(block_cols, block_mask, blocks, codes, scales, mins)
-
-
-def _dequant_spmm_batched_kernel(cols_ref, mask_ref, blocks_ref, codes_ref,
-                                 scales_ref, mins_ref, out_ref, *, m: int,
-                                 block: int):
-    """One (row-block, feature-tile, batch) grid step; ``cols_ref`` is the
-    scalar-prefetched [VB, M] table (fetched once per launch, not per batch
-    element)."""
-    i = pl.program_id(0)
-    acc = jnp.zeros_like(out_ref)
-
-    def body(k, acc):
-        tile = blocks_ref[k]                                    # [B, B]
-        col = cols_ref[i, k]
-        msk = mask_ref[k]
-        codes = codes_ref[pl.dslice(col * block, block), :]     # [B, TF]
-        sc = scales_ref[pl.dslice(col * block, block)]          # [B]
-        mn = mins_ref[pl.dslice(col * block, block)]            # [B]
-        panel = codes.astype(jnp.float32) * sc[:, None] + mn[:, None]
-        return acc + msk * jnp.dot(tile, panel,
-                                   preferred_element_type=jnp.float32)
-
-    acc = jax.lax.fori_loop(0, m, body, acc)
-    out_ref[...] = acc
+    )(block_cols, block_mask, blocks, codes, _row_params(scales, mins))
 
 
 @functools.partial(jax.jit, static_argnames=("block", "f_tile", "interpret"))
@@ -156,9 +160,9 @@ def dequant_spmm_batched(blocks: jnp.ndarray, block_cols: jnp.ndarray,
 
     Batch-axis variant of :func:`dequant_spmm`, mirroring
     ``block_spmm_batched``: one dispatch for the whole micro-batch, shared
-    block-CSR operands, scalar-prefetched ``block_cols``, B innermost in
-    the grid so adjacency tiles amortize across the batch. Per-element
-    results are bit-identical to the unbatched kernel.
+    block-CSR operands, B innermost in the grid so adjacency tiles amortize
+    across the batch. It runs the unbatched kernel body, so per-element
+    results are bit-identical to ``dequant_spmm``.
     """
     vb, m, blk, _ = blocks.shape
     b, v, f = codes.shape
@@ -167,25 +171,27 @@ def dequant_spmm_batched(blocks: jnp.ndarray, block_cols: jnp.ndarray,
     f_tile = min(f_tile, f)
     assert f % f_tile == 0
     grid = (vb, f // f_tile, b)
-    kernel = functools.partial(_dequant_spmm_batched_kernel, m=m, block=block)
+    kernel = functools.partial(_dequant_spmm_kernel, m=m, block=block)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,           # block_cols
+        num_scalar_prefetch=2,           # block_cols, block_mask: SMEM
         grid=grid,
         in_specs=[
-            pl.BlockSpec((None, m), lambda i, j, k, cols: (i, 0)),
             pl.BlockSpec((None, m, block, block),
-                         lambda i, j, k, cols: (i, 0, 0, 0)),
+                         lambda i, j, k, *_: (i, 0, 0, 0)),
             pl.BlockSpec((None, v, f_tile),
-                         lambda i, j, k, cols: (k, 0, j)),   # codes[b]
-            pl.BlockSpec((None, v), lambda i, j, k, cols: (k, 0)),
-            pl.BlockSpec((None, v), lambda i, j, k, cols: (k, 0)),
+                         lambda i, j, k, *_: (k, 0, j)),     # codes[b]
+            pl.BlockSpec((None, 2, v),
+                         lambda i, j, k, *_: (k, 0, 0)),     # scale, min
         ],
         out_specs=pl.BlockSpec((None, block, f_tile),
-                               lambda i, j, k, cols: (k, i, j)),
+                               lambda i, j, k, *_: (k, i, j)),
     )
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, vb * block, f), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=spmm_vmem_limit(m, block, v, f_tile, codes.dtype,
+                                             row_params=True)),
         interpret=interpret,
-    )(block_cols, block_mask, blocks, codes, scales, mins)
+    )(block_cols, block_mask, blocks, codes, _row_params(scales, mins))

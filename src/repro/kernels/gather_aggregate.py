@@ -21,8 +21,9 @@ Kernel body is validated in interpret mode on CPU against ref.block_spmm_ref.
 Both SpMM kernels come in two flavours: the single-query [V, F] form and a
 [B, V, F] *feature-stack* form (``block_spmm_batched``) that serves a whole
 serving micro-batch in one fused dispatch — B is an extra (fastest-varying)
-grid axis so the block-CSR operand loads amortize across the batch, and the
-``block_cols`` table moves to scalar prefetch (``PrefetchScalarGridSpec``).
+grid axis so the block-CSR operand loads amortize across the batch. Both
+share one kernel body and scalar-prefetch the ``block_cols``/``block_mask``
+tables into SMEM (``PrefetchScalarGridSpec``).
 """
 from __future__ import annotations
 
@@ -104,42 +105,68 @@ def build_block_csr(senders: np.ndarray, receivers: np.ndarray,
     return blocks, block_cols, block_mask, padded_v
 
 
+#: Mosaic's default scoped-VMEM limit; a kernel is never given less.
+_DEFAULT_SCOPED_VMEM = 16 * 2**20
+#: Headroom for Mosaic's own scratch on top of the blocks counted below.
+_VMEM_HEADROOM = 4 * 2**20
+
+
+def _vmem_block_bytes(shape, dtype) -> int:
+    """VMEM bytes of one block: its last two dims pad to the native tile
+    (8 sublanes of 32-bit words, 32 rows of 8-bit; 128 lanes)."""
+    itemsize = np.dtype(dtype).itemsize
+    *lead, r, c = shape
+    sub = 8 * 4 // itemsize
+    return (int(np.prod(lead, dtype=np.int64)) * (-(-r // sub) * sub)
+            * (-(-c // 128) * 128) * itemsize)
+
+
+def spmm_vmem_limit(m: int, block: int, src_rows: int, f_tile: int,
+                    src_dtype=jnp.float32, row_params: bool = False) -> int:
+    """Scoped-VMEM limit of one SpMM grid step, from its block shapes.
+
+    Every pipelined block is double-buffered::
+
+        2 * ( M*B*B*4                     adjacency tiles
+            + pad(V)*pad(TF)*itemsize     source panel (f32 or uint8 codes)
+            [ + 8*V*4 ]                   (scale, min) rows, DAQ kernels
+            + B*pad(TF)*4 )               output tile
+        + 2 * B*pad(TF)*4                 accumulator + one dot result
+        + 4 MiB                           Mosaic scratch headroom
+
+    At SIoT full width (M = 127, V = 127*128 = 16256, TF = 52 -> 128 lanes,
+    f32 source) that is 2*(8,323,072 + 8,323,072 + 65,536) + 131,072
+    + 4,194,304 = 37,748,736 bytes: over the 16 MiB default, well inside
+    the 128 MiB of VMEM on a v5e chip.
+    """
+    blocks = _vmem_block_bytes((m, block, block), jnp.float32)
+    panel = _vmem_block_bytes((src_rows, f_tile), src_dtype)
+    params = _vmem_block_bytes((2, src_rows), jnp.float32) if row_params else 0
+    out = _vmem_block_bytes((block, f_tile), jnp.float32)
+    need = 2 * (blocks + panel + params + out) + 2 * out + _VMEM_HEADROOM
+    return max(_DEFAULT_SCOPED_VMEM, need)
+
+
 def _spmm_kernel(cols_ref, mask_ref, blocks_ref, h_ref, out_ref, *, m: int,
                  block: int):
-    """One (row-block, feature-tile) grid step."""
-    acc = jnp.zeros_like(out_ref)
+    """One (row-block, feature-tile[, batch]) grid step.
 
-    def body(k, acc):
-        tile = blocks_ref[k]                      # [B, B]
-        col = cols_ref[k]
-        msk = mask_ref[k]
-        panel = h_ref[pl.dslice(col * block, block), :]   # [B, TF]
-        return acc + msk * jnp.dot(tile, panel,
-                                   preferred_element_type=jnp.float32)
-
-    acc = jax.lax.fori_loop(0, m, body, acc)
-    out_ref[...] = acc
-
-
-def _spmm_batched_kernel(cols_ref, mask_ref, blocks_ref, h_ref, out_ref, *,
-                         m: int, block: int):
-    """One (row-block, feature-tile, batch) grid step.
-
-    ``cols_ref`` is the *whole* [VB, M] column-index table, scalar-prefetched
-    (SMEM-resident) once for the entire launch — the batch axis iterates
-    fastest, so the adjacency tiles and index rows of a block row are
-    fetched once and reused for all B feature stacks.
+    ``cols_ref`` / ``mask_ref`` are the *whole* [VB, M] tile tables,
+    scalar-prefetched into SMEM once per launch and indexed by the row-block
+    id. Mosaic cannot tile a ``(None, M)`` VMEM block of them (the last two
+    block dims must be (8, 128)-aligned or span the array), and SMEM is where
+    a per-tile scalar belongs anyway. With a batch axis it iterates fastest,
+    so a block row's adjacency tiles are fetched once for all B stacks.
     """
     i = pl.program_id(0)
-    acc = jnp.zeros_like(out_ref)
+    acc = jnp.zeros(out_ref.shape, jnp.float32)
 
     def body(k, acc):
         tile = blocks_ref[k]                      # [B, B]
-        col = cols_ref[i, k]
-        msk = mask_ref[k]
-        panel = h_ref[pl.dslice(col * block, block), :]   # [B, TF]
-        return acc + msk * jnp.dot(tile, panel,
-                                   preferred_element_type=jnp.float32)
+        start = pl.multiple_of(cols_ref[i, k] * block, block)
+        panel = h_ref[pl.dslice(start, block), :]         # [B, TF]
+        return acc + mask_ref[i, k] * jnp.dot(
+            tile, panel, preferred_element_type=jnp.float32)
 
     acc = jax.lax.fori_loop(0, m, body, acc)
     out_ref[...] = acc
@@ -155,11 +182,8 @@ def block_spmm_batched(blocks: jnp.ndarray, block_cols: jnp.ndarray,
     Batch-axis variant of :func:`block_spmm`: the same ELL-block-CSR
     operands serve every element of the micro-batch, with the batch as an
     extra (fastest-varying) grid dimension so the adjacency tiles loaded
-    for a block row are amortized across all B stacks, and ``block_cols``
-    moved to ``PrefetchScalarGridSpec`` scalar prefetch so the column-index
-    table is resident once per launch instead of refetched per batch
-    element. Per-(row-block, feature-tile) arithmetic is the exact op
-    sequence of the unbatched kernel, so each ``out[b]`` is bit-identical
+    for a block row are amortized across all B stacks. It runs the same
+    kernel body as the unbatched form, so each ``out[b]`` is bit-identical
     to ``block_spmm(..., h[b])``.
     """
     vb, m, blk, _ = blocks.shape
@@ -168,24 +192,25 @@ def block_spmm_batched(blocks: jnp.ndarray, block_cols: jnp.ndarray,
     f_tile = min(f_tile, f)
     assert f % f_tile == 0, (f, f_tile)
     grid = (vb, f // f_tile, b)
-    kernel = functools.partial(_spmm_batched_kernel, m=m, block=block)
+    kernel = functools.partial(_spmm_kernel, m=m, block=block)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,           # block_cols: whole table, SMEM
+        num_scalar_prefetch=2,           # block_cols, block_mask: SMEM
         grid=grid,
         in_specs=[
-            pl.BlockSpec((None, m), lambda i, j, k, cols: (i, 0)),   # mask
             pl.BlockSpec((None, m, block, block),
-                         lambda i, j, k, cols: (i, 0, 0, 0)),        # tiles
+                         lambda i, j, k, *_: (i, 0, 0, 0)),          # tiles
             pl.BlockSpec((None, v, f_tile),
-                         lambda i, j, k, cols: (k, 0, j)),           # h[b]
+                         lambda i, j, k, *_: (k, 0, j)),             # h[b]
         ],
         out_specs=pl.BlockSpec((None, block, f_tile),
-                               lambda i, j, k, cols: (k, i, j)),
+                               lambda i, j, k, *_: (k, i, j)),
     )
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, vb * block, f), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=spmm_vmem_limit(m, block, v, f_tile)),
         interpret=interpret,
     )(block_cols, block_mask, blocks, h)
 
@@ -211,16 +236,21 @@ def block_spmm(blocks: jnp.ndarray, block_cols: jnp.ndarray,
     assert f % f_tile == 0, (f, f_tile)
     grid = (vb, f // f_tile)
     kernel = functools.partial(_spmm_kernel, m=m, block=block)
-    return pl.pallas_call(
-        kernel,
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,           # block_cols, block_mask: SMEM
         grid=grid,
         in_specs=[
-            pl.BlockSpec((None, m), lambda i, j: (i, 0)),            # cols
-            pl.BlockSpec((None, m), lambda i, j: (i, 0)),            # mask
-            pl.BlockSpec((None, m, block, block), lambda i, j: (i, 0, 0, 0)),
-            pl.BlockSpec((v, f_tile), lambda i, j: (0, j)),          # h panel
+            pl.BlockSpec((None, m, block, block),
+                         lambda i, j, *_: (i, 0, 0, 0)),             # tiles
+            pl.BlockSpec((v, f_tile), lambda i, j, *_: (0, j)),      # h panel
         ],
-        out_specs=pl.BlockSpec((block, f_tile), lambda i, j: (i, j)),
+        out_specs=pl.BlockSpec((block, f_tile), lambda i, j, *_: (i, j)),
+    )
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((vb * block, f), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=spmm_vmem_limit(m, block, v, f_tile)),
         interpret=interpret,
     )(block_cols, block_mask, blocks, h)

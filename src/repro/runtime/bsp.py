@@ -75,11 +75,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:  # jax >= 0.5 exports shard_map at the top level
-    _shard_map = jax.shard_map
-except AttributeError:  # older releases keep it under experimental
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 from repro.api.registry import EXCHANGES
 from repro.gnn.graph import Graph
 from repro.gnn.layers import (EdgeList, LAYER_FNS, apply_layer_with_sum,
@@ -636,17 +631,16 @@ def bsp_apply(params, kind: str, pg: PartitionedGraph, mesh: Mesh,
             for arr in (csr.blocks, csr.cols, csr.mask):
                 operands.append(jnp.asarray(arr))
                 in_specs.append(P(axis, *([None] * (arr.ndim - 1))))
-    smap_kw = {}
-    if use_kernels:
-        # pallas_call has no shard_map replication rule; every operand and
-        # output here is explicitly partitioned, so the check adds nothing.
-        smap_kw["check_rep"] = False
+    # check_vma is off on the kernel path: pallas_call has no shard_map
+    # replication rule, and every operand and output here is explicitly
+    # partitioned, so the check adds nothing.
     fn = _cached_program(
         _program_key("apply", kind, pg, mesh, axis, exchange, use_kernels,
                      halo_quant, interpret),
-        lambda: jax.jit(_shard_map(shard_fn, mesh=mesh,
-                                   in_specs=tuple(in_specs),
-                                   out_specs=spec, **smap_kw)))
+        lambda: jax.jit(jax.shard_map(shard_fn, mesh=mesh,
+                                      in_specs=tuple(in_specs),
+                                      out_specs=spec,
+                                      check_vma=not use_kernels)))
     return fn(list(params), *operands)
 
 
@@ -793,15 +787,13 @@ def bsp_apply_many(params, kind: str, pg: PartitionedGraph,
             for arr in (csr.blocks, csr.cols, csr.mask):
                 operands.append(jnp.asarray(arr))
                 in_specs.append(P(axis, *([None] * (arr.ndim - 1))))
-    smap_kw = {}
-    if use_kernels:
-        smap_kw["check_rep"] = False
     fn = _cached_program(
         _program_key("apply_many", kind, pg, mesh, axis, exchange,
                      use_kernels, halo_quant, interpret),
-        lambda: jax.jit(_shard_map(shard_fn, mesh=mesh,
-                                   in_specs=tuple(in_specs),
-                                   out_specs=spec, **smap_kw)))
+        lambda: jax.jit(jax.shard_map(shard_fn, mesh=mesh,
+                                      in_specs=tuple(in_specs),
+                                      out_specs=spec,
+                                      check_vma=not use_kernels)))
     return fn(list(params), *operands)
 
 
@@ -1043,16 +1035,14 @@ def _bsp_apply_layers(params, kind: str, pg: PartitionedGraph, feats_op,
             for arr in (csr.blocks, csr.cols, csr.mask):
                 operands.append(jnp.asarray(arr))
                 in_specs.append(P(axis, *([None] * (arr.ndim - 1))))
-    smap_kw = {}
-    if use_kernels:
-        smap_kw["check_rep"] = False
     tag = ("frontier" if frontier else "capture") + ("_many" if many else "")
     fn = _cached_program(
         _program_key(tag, kind, pg, mesh, axis, exchange, use_kernels,
                      halo_quant, interpret),
-        lambda: jax.jit(_shard_map(shard_fn, mesh=mesh,
-                                   in_specs=tuple(in_specs),
-                                   out_specs=spec, **smap_kw)))
+        lambda: jax.jit(jax.shard_map(shard_fn, mesh=mesh,
+                                      in_specs=tuple(in_specs),
+                                      out_specs=spec,
+                                      check_vma=not use_kernels)))
     return fn(list(params), *operands)
 
 
@@ -1251,16 +1241,14 @@ def _bsp_apply_stale(params, kind: str, pg: PartitionedGraph, feats_op,
             for arr in (csr.blocks, csr.cols, csr.mask):
                 operands.append(jnp.asarray(arr))
                 in_specs.append(P(axis, *([None] * (arr.ndim - 1))))
-    smap_kw = {}
-    if use_kernels:
-        smap_kw["check_rep"] = False
     tag = "stale_many" if many else "stale"
     fn = _cached_program(
         _program_key(tag, kind, pg, mesh, axis, "halo_async", use_kernels,
                      False, interpret),
-        lambda: jax.jit(_shard_map(shard_fn, mesh=mesh,
-                                   in_specs=tuple(in_specs),
-                                   out_specs=spec, **smap_kw)))
+        lambda: jax.jit(jax.shard_map(shard_fn, mesh=mesh,
+                                      in_specs=tuple(in_specs),
+                                      out_specs=spec,
+                                      check_vma=not use_kernels)))
     tables = [jnp.asarray(t, jnp.float32) for t in halo_tables]
     return fn(list(params), tables, *operands)
 
