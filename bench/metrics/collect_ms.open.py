@@ -1,0 +1,7 @@
+"""collect_ms.open: Milliseconds per request of Session.collect, the DAQ upload
+round trip, from the benchmark's span."""
+from bench import readers
+
+
+def read(m):
+    return readers.span_ms(m, "collect")

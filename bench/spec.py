@@ -1,0 +1,84 @@
+"""Finds a cell's parts by name.
+
+``BENCHMARK.json`` names every configuration, cell and metric. The parts
+live in files of their own, so a later change adds a cell, a traffic mix,
+a configuration or a per-layer metric by adding files and entries:
+
+  * a configuration: the ``file`` its entry names (``bench/configs/``);
+  * a traffic mix: ``bench/traffic/<traffic>.json``;
+  * a per-layer metric: ``bench/metrics/<name>.py``, whose ``read(m)``
+    returns the number, or None where the run has nothing to read;
+  * the chip's peaks: ``bench/peaks.json``, keyed by ``device_kind``.
+"""
+from __future__ import annotations
+
+import importlib.util
+import json
+import pathlib
+from typing import Callable, List, NamedTuple, Optional
+
+ROOT = pathlib.Path(__file__).resolve().parent.parent
+
+
+class Cell(NamedTuple):
+    name: str
+    chips: int
+    config: dict            # the configuration file, plus its "name"
+    traffic: dict           # the traffic file, plus its "name"
+    end_to_end: List[dict]  # the entries that this cell reports
+    per_layer: List[dict]
+
+
+def load_benchmark(root: pathlib.Path = ROOT) -> dict:
+    return json.loads((pathlib.Path(root) / "BENCHMARK.json").read_text())
+
+
+def _named(entries: List[dict], name: str, what: str) -> dict:
+    for e in entries:
+        if e["name"] == name:
+            return e
+    raise KeyError(f"no {what} named {name!r}; have "
+                   f"{', '.join(e['name'] for e in entries)}")
+
+
+def _reported(metric: dict, cell: str) -> bool:
+    return "workloads" not in metric or cell in metric["workloads"]
+
+
+def load_cell(name: str, root: pathlib.Path = ROOT) -> Cell:
+    root = pathlib.Path(root)
+    bench = load_benchmark(root)
+    w = _named(bench["workloads"], name, "workload")
+    c = _named(bench["configs"], w["config"], "configuration")
+    config = dict(json.loads((root / c["file"]).read_text()),
+                  name=c["name"])
+    traffic = dict(json.loads(
+        (root / "bench" / "traffic" / f"{w['traffic']}.json").read_text()),
+        name=w["traffic"])
+    e2e = [m for m in bench["end_to_end"] if _reported(m, name)]
+    moved = {m["name"] for m in e2e}
+    per_layer = [m for m in bench["per_layer"]
+                 if m["moves"] in moved and _reported(m, name)]
+    return Cell(name, int(w["chips"]), config, traffic, e2e, per_layer)
+
+
+def metric_reader(name: str, root: pathlib.Path = ROOT
+                  ) -> Callable[[object], Optional[float]]:
+    """``read`` of ``bench/metrics/<name>.py``."""
+    path = pathlib.Path(root) / "bench" / "metrics" / f"{name}.py"
+    spec = importlib.util.spec_from_file_location(
+        "bench_metric_" + name.replace(".", "_").replace("-", "_"), path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod.read
+
+
+def peaks(device_kind: str, root: pathlib.Path = ROOT) -> dict:
+    """{"flops_per_s", "bytes_per_s", "source"} of one chip of this kind;
+    a kind that the table lacks is an error, never a default."""
+    table = json.loads((pathlib.Path(root) / "bench" / "peaks.json")
+                       .read_text())["chips"]
+    if device_kind not in table:
+        raise KeyError(f"no peaks for device kind {device_kind!r} in "
+                       f"bench/peaks.json; have {', '.join(table)}")
+    return table[device_kind]
