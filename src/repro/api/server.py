@@ -68,6 +68,7 @@ from repro.api.slo import (AdaptiveBatchController, Rejection, SLOPolicy,
                            default_ladder, load_bench_curve)
 from repro.api.updates import GraphDelta, UpdateReport, UpdateRequest
 from repro.core import simulation
+from repro.runtime import tracing
 
 
 @dataclasses.dataclass(frozen=True)
@@ -371,6 +372,10 @@ class Server:
         attached as ``exc.partial_responses``, so mixed streams stay
         recoverable.
         """
+        with tracing.span("server.drain", pending=len(self._pending)):
+            return self._drain()
+
+    def _drain(self) -> List[Union[Response, UpdateResponse, Rejection]]:
         reqs = self._pending
         self._pending = []
         self._svc_cache.clear()   # graph/load/placement may have moved
@@ -747,9 +752,9 @@ class Server:
         ck = (key, batch_size, level, bool(staleness))
         res = self._svc_cache.get(ck)
         if res is None:
-            res = self._session_for(level).account(key,
-                                                   batch_size=batch_size,
-                                                   staleness=staleness)
+            with tracing.span("server.price", batch_size=batch_size):
+                res = self._session_for(level).account(
+                    key, batch_size=batch_size, staleness=staleness)
             self._svc_cache[ck] = res
         return res
 
@@ -876,6 +881,13 @@ class Server:
 
     def _serve_batch(self, batch: List[Request], ready: float, *,
                      level: int = 0) -> List[Response]:
+        with tracing.span("server.batch", batch=self.num_batches,
+                          size=len(batch), request=batch[0].request_id,
+                          level=level):
+            return self._run_batch(batch, ready, level)
+
+    def _run_batch(self, batch: List[Request], ready: float,
+                   level: int) -> List[Response]:
         sess = self._session_for(level)
         b = len(batch)
         key = self._exec_key(batch[0])

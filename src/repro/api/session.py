@@ -38,7 +38,7 @@ from repro.core import simulation
 from repro.core.scheduler import SchedulerState, schedule_step
 from repro.gnn.graph import Graph
 from repro.kernels import ops
-from repro.runtime import bsp
+from repro.runtime import bsp, tracing
 
 
 @dataclasses.dataclass(frozen=True)
@@ -263,7 +263,8 @@ class Session:
         """
         g: Graph = self.plan.graph
         raw = g.features if features is None else np.asarray(features)
-        return self._compressor.roundtrip(raw, g.degrees)
+        with tracing.span("collect", rows=raw.shape[0]):
+            return self._compressor.roundtrip(raw, g.degrees)
 
     def execute(self, feats: np.ndarray, *, executor=None) -> np.ndarray:
         """Stage 2 (paper step 4): distributed runtime (real numerics).
@@ -274,6 +275,10 @@ class Session:
         recomputes only the dirty rows — or runs a full capturing pass
         when the cache cannot serve (always bit-identical either way).
         """
+        with tracing.span("execute", batch_size=1):
+            return self._execute(feats, executor)
+
+    def _execute(self, feats: np.ndarray, executor) -> np.ndarray:
         backend = self.resolve_executor(executor)
         if self._acache is not None:
             return self._cached_execute(np.asarray(feats, np.float32),
@@ -296,6 +301,10 @@ class Session:
         h^0 diffs union into one dirty set; every member stays
         bit-identical to its serial ``execute``).
         """
+        with tracing.span("execute", batch_size=len(feats)):
+            return self._execute_many(feats, executor)
+
+    def _execute_many(self, feats, executor) -> list:
         backend = self.resolve_executor(executor)
         if not (isinstance(feats, np.ndarray) and feats.ndim == 3):
             feats = np.stack([np.asarray(f, np.float32) for f in feats])

@@ -25,6 +25,8 @@ from typing import Callable, Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro.runtime import tracing
+
 try:
     import lz4.frame as _lz4frame
 except ImportError:   # optional dependency (paper's lossless stage)
@@ -56,9 +58,10 @@ def lossless_compress(payload: bytes, codec: str = "zlib"
                       ) -> Tuple[bytes, str]:
     """Compress the shuffled payload; returns (blob, concrete codec)."""
     codec = resolve_lossless_codec(codec)
-    if codec == "lz4":
-        return _lz4frame.compress(payload), "lz4"
-    return zlib.compress(payload, level=6), "zlib"
+    with tracing.span("daq.lossless", in_bytes=len(payload)):
+        if codec == "lz4":
+            return _lz4frame.compress(payload), "lz4"
+        return zlib.compress(payload, level=6), "zlib"
 
 
 # ----------------------------------------------------------------------------
@@ -193,20 +196,21 @@ def daq_pack(features: np.ndarray, degrees: np.ndarray,
     """
     x = np.asarray(features, np.float64)
     degrees = np.asarray(degrees)
-    bpv = assign_bits(degrees, thresholds, bits)
-    groups = {}
-    payload_parts = []
-    for nbits in sorted(set(int(b) for b in bits), reverse=True):
-        ids = np.flatnonzero(bpv == nbits)
-        if ids.size == 0:
-            continue
-        rows = x[ids]
-        if nbits >= 64:
-            q, mins, scales = rows.view(np.uint64), None, None
-        else:
-            q, mins, scales = _quantize_rows(rows, nbits)
-        groups[nbits] = (ids, q, mins, scales)
-        payload_parts.append(byte_shuffle(q))
+    with tracing.span("daq.quantize", rows=x.shape[0]):
+        bpv = assign_bits(degrees, thresholds, bits)
+        groups = {}
+        payload_parts = []
+        for nbits in sorted(set(int(b) for b in bits), reverse=True):
+            ids = np.flatnonzero(bpv == nbits)
+            if ids.size == 0:
+                continue
+            rows = x[ids]
+            if nbits >= 64:
+                q, mins, scales = rows.view(np.uint64), None, None
+            else:
+                q, mins, scales = _quantize_rows(rows, nbits)
+            groups[nbits] = (ids, q, mins, scales)
+            payload_parts.append(byte_shuffle(q))
     payload = used_codec = None
     if lossless:
         payload, used_codec = lossless_compress(b"".join(payload_parts),
@@ -220,12 +224,14 @@ def daq_pack(features: np.ndarray, degrees: np.ndarray,
 def daq_unpack(packed: PackedFeatures) -> np.ndarray:
     """Dequantize back to the original bitwidth (float64) in vertex order —
     the fog-side unpacking step; the 64-bit bin is exactly lossless."""
-    out = np.zeros((packed.num_vertices, packed.feature_dim), np.float64)
-    for nbits, (ids, q, mins, scales) in packed.groups.items():
-        if nbits >= 64:
-            out[ids] = q.view(np.float64)
-        else:
-            out[ids] = _dequantize_rows(q, mins, scales)
+    with tracing.span("daq.dequantize", rows=packed.num_vertices):
+        out = np.zeros((packed.num_vertices, packed.feature_dim),
+                       np.float64)
+        for nbits, (ids, q, mins, scales) in packed.groups.items():
+            if nbits >= 64:
+                out[ids] = q.view(np.float64)
+            else:
+                out[ids] = _dequantize_rows(q, mins, scales)
     return out
 
 

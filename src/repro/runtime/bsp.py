@@ -84,6 +84,7 @@ from repro.kernels.gather_aggregate import (BLOCK, block_spmm,
                                             block_spmm_batched,
                                             build_block_csr,
                                             padded_feature_dim)
+from repro.runtime import tracing
 
 #: legal values of the Engine/Session ``aggregation`` knob.
 AGGREGATIONS = ("segment_sum", "pallas", "auto")
@@ -253,6 +254,20 @@ class PartitionedGraph:
         feats = np.zeros((self.n, b, self.slots, f), np.float32)
         feats[self.part_of, :, self.slot_of] = np.moveaxis(features, 0, 1)
         return feats
+
+    def shard_inputs(self, feats: np.ndarray, kernels: bool) -> list:
+        """The host arrays a shard_map program takes after the params, in
+        operand order: ``feats`` (the [n, P, F] or [n, B, P, F] table),
+        the partition layout and, on the kernel path, both block-CSR
+        shards."""
+        arrays = [feats, self.vertex_mask, self.senders_global,
+                  self.senders_halo, self.receivers_local, self.edge_mask,
+                  self.boundary_rows, self.boundary_mask,
+                  self.self_senders_global, self.self_senders_halo]
+        if kernels:
+            for csr in (self.local_csr, self.halo_csr):
+                arrays += [csr.blocks, csr.cols, csr.mask]
+        return arrays
 
     def with_features(self, features: np.ndarray) -> "PartitionedGraph":
         """Same layout (and block-CSR shards), fresh per-vertex features.
@@ -614,23 +629,12 @@ def bsp_apply(params, kind: str, pg: PartitionedGraph, mesh: Mesh,
         return h[None]
 
     spec = P(axis, None, None)
-    spec2 = P(axis, None)
+    host = pg.shard_inputs(pg.feats, use_kernels)
     # P() as a pytree-prefix spec: the model params ride along as a fully
     # replicated *operand* (not a closure constant), so the compiled
     # program below is reusable across queries and plans.
-    in_specs = [P(), spec, spec2, spec2, spec2, spec2, spec2, spec2, spec2,
-                spec2, spec2]
-    operands = [jnp.asarray(pg.feats), jnp.asarray(pg.vertex_mask),
-                jnp.asarray(pg.senders_global), jnp.asarray(pg.senders_halo),
-                jnp.asarray(pg.receivers_local), jnp.asarray(pg.edge_mask),
-                jnp.asarray(pg.boundary_rows), jnp.asarray(pg.boundary_mask),
-                jnp.asarray(pg.self_senders_global),
-                jnp.asarray(pg.self_senders_halo)]
-    if use_kernels:
-        for csr in (pg.local_csr, pg.halo_csr):
-            for arr in (csr.blocks, csr.cols, csr.mask):
-                operands.append(jnp.asarray(arr))
-                in_specs.append(P(axis, *([None] * (arr.ndim - 1))))
+    in_specs = [P()] + [P(axis, *([None] * (a.ndim - 1))) for a in host]
+    operands = [jnp.asarray(a) for a in host]
     # check_vma is off on the kernel path: pallas_call has no shard_map
     # replication rule, and every operand and output here is explicitly
     # partitioned, so the check adds nothing.
@@ -771,22 +775,11 @@ def bsp_apply_many(params, kind: str, pg: PartitionedGraph,
         return h[None]
 
     spec = P(axis, None, None, None)
-    spec2 = P(axis, None)
+    host = pg.shard_inputs(feat_stack, use_kernels)
     # Params ride as a replicated operand (P() pytree-prefix spec) so the
     # compiled program is reusable — see _PROGRAM_CACHE.
-    in_specs = [P(), spec, spec2, spec2, spec2, spec2, spec2, spec2, spec2,
-                spec2, spec2]
-    operands = [jnp.asarray(feat_stack), jnp.asarray(pg.vertex_mask),
-                jnp.asarray(pg.senders_global), jnp.asarray(pg.senders_halo),
-                jnp.asarray(pg.receivers_local), jnp.asarray(pg.edge_mask),
-                jnp.asarray(pg.boundary_rows), jnp.asarray(pg.boundary_mask),
-                jnp.asarray(pg.self_senders_global),
-                jnp.asarray(pg.self_senders_halo)]
-    if use_kernels:
-        for csr in (pg.local_csr, pg.halo_csr):
-            for arr in (csr.blocks, csr.cols, csr.mask):
-                operands.append(jnp.asarray(arr))
-                in_specs.append(P(axis, *([None] * (arr.ndim - 1))))
+    in_specs = [P()] + [P(axis, *([None] * (a.ndim - 1))) for a in host]
+    operands = [jnp.asarray(a) for a in host]
     fn = _cached_program(
         _program_key("apply_many", kind, pg, mesh, axis, exchange,
                      use_kernels, halo_quant, interpret),
@@ -1044,6 +1037,13 @@ def _bsp_apply_layers(params, kind: str, pg: PartitionedGraph, feats_op,
                                       out_specs=spec,
                                       check_vma=not use_kernels)))
     return fn(list(params), *operands)
+
+
+def wait(out: jax.Array) -> jax.Array:
+    """Block on a dispatched result under its own span; the host copy
+    after it would block anyway, so no synchronization is added."""
+    with tracing.span("execute.wait"):
+        return jax.block_until_ready(out)
 
 
 def _default_mesh(pg: PartitionedGraph, axis: str) -> Mesh:
@@ -1358,24 +1358,22 @@ def bsp_infer(params, kind: str, g: Graph, assignment: np.ndarray,
     partition buffers (the features are refreshed from ``g``), which is
     what the serving path does per query.
     """
-    if pg is None:
-        mode = resolve_aggregation(aggregation, kind, exchange=exchange)
-        pg = build_partitioned(g, assignment,
-                               build_blocks=mode == "pallas")
-    else:
-        pg = pg.with_features(g.features)
-    if mesh is None:
-        devs = np.array(jax.devices()[:pg.n])
-        if len(devs) != pg.n:
-            raise ValueError(
-                f"need {pg.n} devices for {pg.n} partitions, have "
-                f"{len(jax.devices())} — run under "
-                f"XLA_FLAGS=--xla_force_host_platform_device_count={pg.n}")
-        mesh = Mesh(devs, (axis,))
-    out = np.asarray(bsp_apply(params, kind, pg, mesh, axis, exchange,
-                               aggregation=aggregation,
-                               halo_quant=halo_quant))
-    return pg.unpermute(out)
+    with tracing.span("execute.dispatch") as span:
+        kernels = resolve_aggregation(aggregation, kind,
+                                      exchange=exchange) == "pallas"
+        if pg is None:
+            pg = build_partitioned(g, assignment, build_blocks=kernels)
+        else:
+            pg = pg.with_features(g.features)
+        if mesh is None:
+            mesh = _default_mesh(pg, axis)
+        span.set_metadata(upload_bytes=sum(
+            a.nbytes for a in pg.shard_inputs(pg.feats, kernels)))
+        out = bsp_apply(params, kind, pg, mesh, axis, exchange,
+                        aggregation=aggregation, halo_quant=halo_quant)
+    out = wait(out)
+    with tracing.span("execute.download", download_bytes=out.nbytes):
+        return pg.unpermute(np.asarray(out))
 
 
 def bsp_infer_many(params, kind: str, feats: np.ndarray,
@@ -1393,19 +1391,19 @@ def bsp_infer_many(params, kind: str, feats: np.ndarray,
     if feats.ndim != 3:
         raise ValueError(f"bsp_infer_many takes a [B, V, F] stack, got "
                          f"shape {feats.shape}")
-    stack = pg.feature_stack(feats)
-    if mesh is None:
-        devs = np.array(jax.devices()[:pg.n])
-        if len(devs) != pg.n:
-            raise ValueError(
-                f"need {pg.n} devices for {pg.n} partitions, have "
-                f"{len(jax.devices())} — run under "
-                f"XLA_FLAGS=--xla_force_host_platform_device_count={pg.n}")
-        mesh = Mesh(devs, (axis,))
-    out = np.asarray(bsp_apply_many(params, kind, pg, stack, mesh, axis,
-                                    exchange, aggregation=aggregation,
-                                    halo_quant=halo_quant))
-    return pg.unpermute_stack(out)
+    with tracing.span("execute.dispatch") as span:
+        stack = pg.feature_stack(feats)
+        if mesh is None:
+            mesh = _default_mesh(pg, axis)
+        kernels = resolve_aggregation(aggregation, kind,
+                                      exchange=exchange) == "pallas"
+        span.set_metadata(upload_bytes=sum(
+            a.nbytes for a in pg.shard_inputs(stack, kernels)))
+        out = bsp_apply_many(params, kind, pg, stack, mesh, axis, exchange,
+                             aggregation=aggregation, halo_quant=halo_quant)
+    out = wait(out)
+    with tracing.span("execute.download", download_bytes=out.nbytes):
+        return pg.unpermute_stack(np.asarray(out))
 
 
 def exchange_bytes(pg: PartitionedGraph, feature_dim: int,
