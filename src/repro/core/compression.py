@@ -15,10 +15,17 @@ warning. The default stays zlib so wire-byte accounting is stable across
 environments. The shuffle transposes the byte planes of fixed-width
 elements, which groups the mostly-zero high bytes of sparse/quantized
 features and greatly improves either entropy coder's ratio.
+
+The lossless stage only sizes the wire (``end_to_end_sizes`` and the
+simulator's ``_partition_wire_bytes``): being lossless, it cannot change
+what the fogs unpack. So a COMPRESSORS round trip quantizes and
+dequantizes, and carries exactly the quantization error, without running
+the shuffle or the coder.
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
 import warnings
 import zlib
 from typing import Callable, Optional, Sequence, Tuple
@@ -186,7 +193,7 @@ def daq_pack(features: np.ndarray, degrees: np.ndarray,
              bits: Sequence[int] = DEFAULT_BITS,
              lossless: bool = True,
              codec: str = "zlib") -> PackedFeatures:
-    """Quantize features degree-aware, then shuffle + losslessly compress.
+    """Quantize features degree-aware, then (``lossless``) shuffle + compress.
 
     The input is treated as Q=64-bit (the paper's raw feature width); the
     64-bit bin stores float64 verbatim (no quantization error). ``codec``
@@ -199,7 +206,6 @@ def daq_pack(features: np.ndarray, degrees: np.ndarray,
     with tracing.span("daq.quantize", rows=x.shape[0]):
         bpv = assign_bits(degrees, thresholds, bits)
         groups = {}
-        payload_parts = []
         for nbits in sorted(set(int(b) for b in bits), reverse=True):
             ids = np.flatnonzero(bpv == nbits)
             if ids.size == 0:
@@ -210,11 +216,12 @@ def daq_pack(features: np.ndarray, degrees: np.ndarray,
             else:
                 q, mins, scales = _quantize_rows(rows, nbits)
             groups[nbits] = (ids, q, mins, scales)
-            payload_parts.append(byte_shuffle(q))
+        if lossless:
+            shuffled = b"".join(byte_shuffle(q) for _, q, _, _ in
+                                groups.values())
     payload = used_codec = None
     if lossless:
-        payload, used_codec = lossless_compress(b"".join(payload_parts),
-                                                codec)
+        payload, used_codec = lossless_compress(shuffled, codec)
     return PackedFeatures(num_vertices=x.shape[0], feature_dim=x.shape[1],
                           bits_per_vertex=bpv, groups=groups,
                           lossless_payload=payload,
@@ -247,37 +254,37 @@ def uniform_pack(features: np.ndarray, nbits: int = 8,
 class Compressor:
     """A COMPRESSORS registry entry: one device-upload codec.
 
-    ``roundtrip`` packs and unpacks features exactly as devices/fogs would,
-    so downstream numerics carry the true quantization error. ``sim_key``
-    is the wire-byte accounting key understood by
-    ``simulation._partition_wire_bytes`` (None = raw upload).
+    ``roundtrip`` quantizes and dequantizes features exactly as devices
+    and fogs would, so downstream numerics carry the true quantization
+    error. It skips the lossless stage, which cannot change the unpacked
+    rows: the wire is sized apart, from ``sim_key``, the key understood
+    by ``simulation._partition_wire_bytes`` (None = raw upload).
+    ``quantize`` is the codec's pack without its lossless stage.
     """
     name: str
     sim_key: Optional[str]
-    pack: Optional[Callable[[np.ndarray, np.ndarray], PackedFeatures]]
+    quantize: Optional[Callable[[np.ndarray, np.ndarray], PackedFeatures]]
 
     def roundtrip(self, features: np.ndarray,
                   degrees: np.ndarray) -> np.ndarray:
-        if self.pack is None:
+        if self.quantize is None:
             return np.asarray(features, np.float32)
-        packed = self.pack(np.asarray(features, np.float64), degrees)
+        packed = self.quantize(np.asarray(features, np.float64), degrees)
         return daq_unpack(packed).astype(np.float32)
 
 
 def _register_compressors():
     from repro.api.registry import COMPRESSORS
+    daq = functools.partial(daq_pack, lossless=False)
     COMPRESSORS.register("none", Compressor("none", None, None))
-    COMPRESSORS.register("daq", Compressor(
-        "daq", "daq", lambda x, d: daq_pack(x, d)))
-    COMPRESSORS.register("daq_noll", Compressor(
-        "daq_noll", "daq_noll", lambda x, d: daq_pack(x, d, lossless=False)))
-    # The paper's LZ4 lossless stage (optional lz4 dep; zlib fallback with
-    # a warning). Numerics are identical to "daq" — only the lossless
-    # payload (and hence the wire bytes) differs.
-    COMPRESSORS.register("daq_lz4", Compressor(
-        "daq_lz4", "daq_lz4", lambda x, d: daq_pack(x, d, codec="lz4")))
+    # The DAQ entries quantize alike; they differ only in the lossless
+    # stage (none, zlib, or the paper's LZ4 via the optional lz4 dep with
+    # a zlib fallback), hence only in the wire bytes priced.
+    for name in ("daq", "daq_noll", "daq_lz4"):
+        COMPRESSORS.register(name, Compressor(name, name, daq))
     COMPRESSORS.register("uniform8", Compressor(
-        "uniform8", "uniform8", lambda x, d: uniform_pack(x, 8)))
+        "uniform8", "uniform8",
+        lambda x, d: uniform_pack(x, 8, lossless=False)))
 
 
 _register_compressors()

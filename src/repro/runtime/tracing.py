@@ -35,10 +35,12 @@ SPANS: Dict[str, str] = {
                     "(simulation.simulate) [batch_size]",
     "collect": "Collect: Session.collect, one request's upload round trip "
                "[rows]",
-    "daq.quantize": "Collect: daq_pack's bit assignment, row quantization "
-                    "and byte shuffle (uniform_pack too) [rows]",
-    "daq.lossless": "Collect: lossless_compress of the shuffled payload "
-                    "[in_bytes]",
+    "daq.quantize": "Collect: daq_pack's bit assignment and row "
+                    "quantization, plus the byte shuffle where the lossless "
+                    "stage follows (uniform_pack too) [rows]",
+    "daq.lossless": "Server: lossless_compress of the shuffled payload, "
+                    "sizing the wire bytes pricing charges (under "
+                    "server.price; never in a collect) [in_bytes]",
     "daq.dequantize": "Collect: daq_unpack [rows]",
     "execute": "Executor: Session.execute / execute_many [batch_size]",
     "execute.dispatch": "Executor: backend entry until the jitted call "

@@ -1,10 +1,16 @@
-"""DAQ + lossless compression: Thm 2 exactness, round-trip error bounds."""
+"""DAQ + lossless compression: Thm 2 exactness, round-trip error bounds,
+and a round trip that leaves the lossless stage to wire sizing."""
+import warnings
+import zlib
+
 import numpy as np
 import pytest
 from _hypothesis_compat import given, settings, st  # optional dep:
 # property tests skip cleanly when hypothesis is not installed
 
+from repro.api.registry import COMPRESSORS
 from repro.core import compression as comp
+from repro.core import simulation
 from repro.gnn import datasets
 from repro.gnn.graph import degree_cdf
 
@@ -97,3 +103,101 @@ def test_uniform8_smaller_but_lossier_than_daq():
     err_daq = np.abs(comp.daq_unpack(daq) - feats).mean()
     err_uni = np.abs(comp.daq_unpack(uni) - feats).mean()
     assert err_daq <= err_uni + 1e-9
+
+
+# --- the round trip skips the lossless stage, which only sizes the wire ---
+
+def _sparse_onehot(rng):
+    """SIoT-like uploads: four one-hot blocks of 13 categories per row."""
+    x = np.zeros((600, 52), np.float32)
+    for b in range(4):
+        x[np.arange(600), 13 * b + rng.integers(0, 13, 600)] = 1.0
+    return x
+
+
+def _dense_noisy(rng):
+    """Yelp-like uploads: dense features plus a little noise."""
+    x = rng.normal(size=(400, 100))
+    return (x + 0.1 * rng.normal(size=x.shape)).astype(np.float32)
+
+
+TABLES = {"sparse_onehot": _sparse_onehot, "dense_noisy": _dense_noisy}
+
+#: Each COMPRESSORS entry's pack as the round trip once ran it, lossless
+#: stage included.
+PACK_WITH_LOSSLESS = {
+    "daq": lambda x, d: comp.daq_pack(x, d),
+    "daq_noll": lambda x, d: comp.daq_pack(x, d, lossless=False),
+    "daq_lz4": lambda x, d: comp.daq_pack(x, d, codec="lz4"),
+    "uniform8": lambda x, d: comp.uniform_pack(x, 8),
+}
+
+
+def _table(kind):
+    rng = np.random.default_rng(7)
+    x = TABLES[kind](rng)
+    return x, rng.zipf(1.6, size=x.shape[0]).astype(np.int64)
+
+
+@pytest.mark.parametrize("kind", sorted(TABLES))
+@pytest.mark.parametrize("name", sorted(PACK_WITH_LOSSLESS) + ["none"])
+def test_roundtrip_is_bitwise_the_unpack_of_the_lossless_pack(name, kind):
+    x, d = _table(kind)
+    got = COMPRESSORS.resolve(name).roundtrip(x, d)
+    if name == "none":
+        want = x.astype(np.float32)
+    else:
+        with warnings.catch_warnings():   # lz4 may fall back to zlib
+            warnings.simplefilter("ignore", RuntimeWarning)
+            packed = PACK_WITH_LOSSLESS[name](x.astype(np.float64), d)
+        assert packed.lossless_payload is not None or name == "daq_noll"
+        want = comp.daq_unpack(packed).astype(np.float32)
+    assert got.dtype == want.dtype == np.float32
+    assert got.tobytes() == want.tobytes()
+
+
+@pytest.mark.parametrize("name", sorted(PACK_WITH_LOSSLESS))
+def test_roundtrip_never_runs_the_lossless_coder(monkeypatch, name):
+    calls = {"lossless": 0, "shuffle": 0}
+
+    def counted(key, fn):
+        def wrapper(*a, **kw):
+            calls[key] += 1
+            return fn(*a, **kw)
+        return wrapper
+
+    monkeypatch.setattr(comp, "lossless_compress",
+                        counted("lossless", comp.lossless_compress))
+    monkeypatch.setattr(comp, "byte_shuffle",
+                        counted("shuffle", comp.byte_shuffle))
+    x, d = _table("sparse_onehot")
+    COMPRESSORS.resolve(name).roundtrip(x, d)
+    assert calls == {"lossless": 0, "shuffle": 0}
+    comp.daq_pack(x, d)   # the sizing pack still runs both
+    assert calls["lossless"] == 1 and calls["shuffle"] > 0
+
+
+def _zlib_size(x, d):
+    """zlib level 6 over the byte-shuffled DAQ groups, widest bits first."""
+    groups = comp.daq_pack(x, d, lossless=False).groups
+    payload = b"".join(comp.byte_shuffle(groups[b][1])
+                       for b in sorted(groups, reverse=True))
+    return len(zlib.compress(payload, 6))
+
+
+@pytest.mark.parametrize("kind", sorted(TABLES))
+def test_end_to_end_sizes_still_report_the_zlib_size(kind):
+    x, d = _table(kind)
+    x = x.astype(np.float64)
+    sizes = comp.end_to_end_sizes(x, d)
+    assert sizes["wire_bytes"] == _zlib_size(x, d)
+    assert sizes["wire_bytes"] < sizes["daq_bytes"]
+
+
+def test_partition_wire_bytes_still_price_the_zlib_size():
+    g = datasets.load("siot", scale=0.05, seed=0)
+    ids = np.arange(g.num_vertices)
+    feats = g.features.astype(np.float64)
+    overhead = g.num_vertices * simulation.PROTOCOL_BYTES_PER_VERTEX
+    assert simulation._partition_wire_bytes(g, ids, "daq") == (
+        overhead + _zlib_size(feats, g.degrees))

@@ -60,10 +60,11 @@ def test_span_carries_counters_and_late_metadata(tmp_path):
 
 @pytest.mark.parametrize("aggregation", ["segment_sum", "pallas"])
 def test_query_spans_nest_where_the_work_happens(tmp_path, aggregation):
-    """Session.query outside a Server: collect holds the codec's three
-    stages, execute the executor's three, and the upload counts the
-    feature table and the edge arrays. (The query's pricing packs the
-    stored table once more, outside both.)"""
+    """Session.query outside a Server: collect holds the codec's quantize
+    and dequantize, execute the executor's three stages, and the upload
+    counts the feature table and the edge arrays. The query's pricing
+    packs the stored table once more, outside both, and only it runs the
+    lossless stage: the round trip leaves it to the wire's sizing."""
     g = datasets.load("siot", scale=0.02, seed=0)
     params = models.gnn_init(jax.random.PRNGKey(0), "gcn",
                              [g.feature_dim, 8, 4])
@@ -80,9 +81,14 @@ def test_query_spans_nest_where_the_work_happens(tmp_path, aggregation):
                        "daq.dequantize", "execute", "execute.dispatch",
                        "execute.wait", "execute.download"}
     (collect,), (execute,) = by["collect"], by["execute"]
-    for name in ("daq.quantize", "daq.lossless", "daq.dequantize"):
-        assert sum(_within(ev, collect) for ev in by[name]) == 1
-    assert len(by["daq.quantize"]) == len(by["daq.lossless"]) == 2
+    (lossless,), (dequantize,) = by["daq.lossless"], by["daq.dequantize"]
+    assert not _within(lossless, collect) and not _within(lossless, execute)
+    assert _within(dequantize, collect)
+    assert len(by["daq.quantize"]) == 2
+    inside = [_within(ev, collect) for ev in by["daq.quantize"]]
+    assert sum(inside) == 1
+    priced, = [ev for ev, i in zip(by["daq.quantize"], inside) if not i]
+    assert priced[2] <= lossless[1]
     for name in ("execute.dispatch", "execute.wait", "execute.download"):
         assert len(by[name]) == 1 and _within(by[name][0], execute)
     assert collect[3] == {"rows": g.num_vertices}
