@@ -12,8 +12,11 @@ sizes, and prints one line with the number the check compares,
   * ``program``: the answers against the reference at the precision the
     configuration states (a sound run's reading);
   * ``control``: the reference computed one precision lower
-    (``reference.CONTROL``), put in the program's place, which a limit
-    has to fail.
+    (``reference.CONTROL``, with the configuration's halo wire), put in
+    the program's place, which a limit has to fail;
+  * ``no_wire`` (null where no wire is stated): the answers against the
+    reference that sends the halo rows exact, which shows how much of
+    ``program`` the modelled wire accounts for.
 
 The benchmark's own runs do not run it. Needs the chip, as ``run.py``.
 """
@@ -48,28 +51,38 @@ def serve_pool(cell, seed, scale=None):
 
 
 def readings(cell, seed, platform="tpu", scale=None):
-    """The program's and the control's ``rms_err`` on one seed."""
+    """The program's and the control's ``rms_err`` on one seed, and the
+    program's against the reference without the wire."""
     import numpy as np
 
-    from bench import reference
+    from bench import harness, reference
 
     b, answers = serve_pool(cell, seed, scale)
     params = [{n: np.asarray(v) for n, v in p.items()} for p in b.params]
     precs = reference.stated(cell.config, platform)
+    cross = harness.wire(b, precs)
+    low_prec = reference.CONTROL._replace(halo=precs[0].halo)
     prog = ctl = 0.0
+    no_wire = None if cross is None else 0.0
     for p in range(len(b.pool)):
         x = reference.daq(b.pool[p], b.edges.degree)
-        refs = [reference.forward(b.kind, params, b.edges, x, prec)
+        refs = [reference.forward(b.kind, params, b.edges, x, prec, cross)
                 for prec in precs]
-        low, _ = reference.forward(b.kind, params, b.edges, x,
-                                   reference.CONTROL)
+        low, _ = reference.forward(b.kind, params, b.edges, x, low_prec,
+                                   cross)
         ctl = max(ctl, min(reference.scaled_rms(low, *r) for r in refs))
+        exact = (None if cross is None else
+                 [reference.forward(b.kind, params, b.edges, x, prec)
+                  for prec in precs])
         for k, got in answers.items():
             if k % len(b.pool) == p:
                 prog = max(prog, min(reference.scaled_rms(got, *r)
                                      for r in refs))
+                if exact is not None:
+                    no_wire = max(no_wire, min(
+                        reference.scaled_rms(got, *r) for r in exact))
     return {"seed": seed, "answers": len(answers), "program": prog,
-            "control": ctl}
+            "control": ctl, "no_wire": no_wire}
 
 
 def main(argv=None) -> int:

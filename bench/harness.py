@@ -15,7 +15,6 @@ configuration states.
 from __future__ import annotations
 
 import gc
-import math
 import shutil
 import sys
 import tempfile
@@ -67,25 +66,14 @@ def use_compile_cache(root) -> str:
 
 
 def make_params(kind: str, widths: List[int], seed: int):
-    """Glorot-uniform weights and small uniform biases, float32, made on
+    """The kind's weights (``init`` of ``bench/kinds/<kind>.py``), made on
     the device in one jitted call from the seed."""
     import jax
-    import jax.numpy as jnp
 
-    def init(key):
-        out = []
-        for fi, rows, fo in work.layer_widths(kind, widths):
-            key, kw, kb = jax.random.split(key, 3)
-            lim = math.sqrt(6.0 / (rows + fo))
-            out.append({"w": jax.random.uniform(kw, (rows, fo), jnp.float32,
-                                                -lim, lim),
-                        "b": jax.random.uniform(kb, (fo,), jnp.float32,
-                                                -0.1, 0.1)})
-        return out
-
+    model = spec.model_kind(kind)
     key = jax.random.fold_in(jax.random.PRNGKey(seed & 0xFFFFFFFF),
                              seed >> 32)
-    return jax.jit(init)(key)
+    return jax.jit(lambda k: model.init(k, widths))(key)
 
 
 class CompileCounter:
@@ -142,12 +130,14 @@ def build(cell: spec.Cell, seed: int, scale: Optional[float] = None):
                                int(cell.traffic["uploads"]["pool"]),
                                cfg["uploads"], seed)
     edges = reference.directed_edges(raw.num_vertices, raw.edges)
-    halo = (work.halo_pairs(edges.senders, edges.receivers,
-                            plan.placement.assignment)
+    # The vertex -> fog map in the raw graph's vertex order, which the
+    # check still needs once the plan is freed.
+    assign = np.array(plan.placement.assignment, copy=True)
+    halo = (work.halo_pairs(edges.senders, edges.receivers, assign)
             if plan.num_fogs > 1 and eng["executor"] == "mesh-bsp" else 0)
     return SimpleNamespace(
         kind=model["kind"], widths=widths, params=params, server=server,
-        plan=plan, pool=pool, edges=edges, halo=halo,
+        plan=plan, pool=pool, edges=edges, assign=assign, halo=halo,
         v=raw.num_vertices, e=len(edges.senders),
         max_batch=int(cfg["max_batch"]))
 
@@ -180,13 +170,24 @@ def batch_sizes(cell: spec.Cell, max_batch: int) -> List[int]:
     return list(range(1, max_batch + 1))
 
 
+def wire(b, precisions) -> Optional[np.ndarray]:
+    """Per directed edge, whether its sender and receiver sit on different
+    fogs, where a stated precision rounds the rows that cross the wire
+    between them; None where none does."""
+    if all(p.halo is None for p in precisions):
+        return None
+    return b.assign[b.edges.senders] != b.assign[b.edges.receivers]
+
+
 def check(b, run: traffic.Run, limit: float):
     """Compare every answer with the reference of its upload, computed at
     each rounding that the configuration's precision admits on this
-    platform, by ``rms_err`` against the nearest of them.
+    platform (the halo wire's included), by ``rms_err`` against the
+    nearest of them.
 
     Returns (numbers compared with their limits, requests that failed)."""
     refs = {}
+    cross = wire(b, b.precisions)
     worst, failed = 0.0, 0
     for k in range(len(run.done)):
         if k not in run.answers:
@@ -196,7 +197,8 @@ def check(b, run: traffic.Run, limit: float):
         if p not in refs:
             x = reference.daq(b.pool[p], b.edges.degree)
             refs[p] = [reference.forward(b.kind, b.params_np, b.edges, x,
-                                         prec) for prec in b.precisions]
+                                         prec, cross)
+                       for prec in b.precisions]
         err = min(reference.scaled_rms(run.answers[k], *ref)
                   for ref in refs[p])
         worst = max(worst, err)

@@ -21,6 +21,19 @@ so too; an answer is held to the nearer of those two roundings.
 its features and activations in bfloat16 would compute it on the chip:
 every array stored in bfloat16, every matmul on bfloat16 operands with
 float32 accumulation. It has to fail the check.
+
+Where the graph is split over fogs and the configuration states that rows
+cross between chips as 8-bit codes (``Precision.halo``), ``forward`` takes
+``wire``, true for each directed edge whose sender and receiver sit on
+different fogs. Over such an edge the receiver reads the sender's row
+after a per-row round trip, all in float32: over the layer's input width,
+``lo = min``, ``step = max(max - lo, 1e-12) / 255``, codes
+``clip(rint((h - lo) / step), 0, 255)``, value ``code * step + lo``. The
+SpMM's rounding then applies to that value as to any feature operand.
+Local edges and the vertex's own row read the exact row.
+
+Each model kind's layer lives in ``bench/kinds/<kind>.py``
+(``reference_layer``), found by name as ``bench/spec.py`` says.
 """
 from __future__ import annotations
 
@@ -29,6 +42,8 @@ from typing import NamedTuple, Optional, Sequence, Tuple
 import ml_dtypes
 import numpy as np
 import scipy.sparse
+
+from bench import spec
 
 BF16 = np.dtype(ml_dtypes.bfloat16)
 
@@ -81,12 +96,14 @@ class Precision(NamedTuple):
 
     ``store``: every array kept between operations (the upload, each
     neighbour-sum, each layer's output); ``dense``: both operands of the
-    dense matmuls; ``spmm``: the feature operand of the neighbour-sum.
+    dense matmuls; ``spmm``: the feature operand of the neighbour-sum;
+    ``halo``: the integer codes of the rows that cross between fogs.
     Element-wise arithmetic runs in float32 and sums accumulate exactly,
     then round to float32, as the chip's matrix unit accumulates."""
     store: Optional[np.dtype] = None
     dense: Optional[np.dtype] = None
     spmm: Optional[np.dtype] = None
+    halo: Optional[np.dtype] = None
 
 
 CONTROL = Precision(store=BF16, dense=BF16, spmm=BF16)
@@ -114,39 +131,81 @@ def _matmul(x: np.ndarray, w: np.ndarray, dt) -> np.ndarray:
             @ _round(w, dt).astype(np.float64)).astype(np.float32)
 
 
+def wire_round_trip(h: np.ndarray, codes: np.dtype) -> np.ndarray:
+    """Each row as it reads after crossing the wire as ``codes`` (uint8:
+    255 levels) with a float32 (step, min) pair, all in float32."""
+    h = np.asarray(h, np.float32)
+    levels = np.float32(np.iinfo(codes).max)
+    lo = h.min(axis=-1, keepdims=True)
+    step = np.maximum(h.max(axis=-1, keepdims=True) - lo,
+                      np.float32(1e-12)) / levels
+    return np.clip(np.rint((h - lo) / step), 0, levels) * step + lo
+
+
+class Layer(NamedTuple):
+    """What one layer of the reference reads: a kind's ``reference_layer``
+    gets it. ``parts`` pairs each adjacency ([receiver, sender] ones) with
+    the rows its senders are read as: one pair, or the local edges with
+    the exact rows and the wire's edges with the round-tripped ones, each
+    rounded as the SpMM's feature operand."""
+    h: np.ndarray               # [V, F] input rows as stored
+    deg: np.ndarray             # [V, 1] float32 in-degree
+    parts: Tuple[Tuple[scipy.sparse.csr_matrix, np.ndarray], ...]
+    store: Optional[np.dtype]
+    dense: Optional[np.dtype]
+
+    def neighbour_sum(self) -> np.ndarray:
+        """[V, F] sum over u->v of the sender's row, summed exactly, then
+        rounded as stored."""
+        (adj, rows), *rest = self.parts
+        a = adj @ rows.astype(np.float64)
+        for adj, rows in rest:
+            a = a + adj @ rows.astype(np.float64)
+        return _round(a.astype(np.float32), self.store)
+
+    def matmul(self, x: np.ndarray, w: np.ndarray) -> np.ndarray:
+        return _matmul(x, w, self.dense)
+
+
+def _adjacency(e: Edges, keep: np.ndarray) -> scipy.sparse.csr_matrix:
+    return scipy.sparse.csr_matrix(
+        (np.ones(int(keep.sum())), (e.receivers[keep], e.senders[keep])),
+        shape=(e.num_vertices, e.num_vertices))
+
+
 def forward(kind: str, params: Sequence[dict], e: Edges, feats: np.ndarray,
-            prec: Precision = Precision()) -> Tuple[np.ndarray, np.ndarray]:
+            prec: Precision = Precision(),
+            wire: Optional[np.ndarray] = None
+            ) -> Tuple[np.ndarray, np.ndarray]:
     """[V, F] collected features -> ([V, D] float32 embeddings, [V] row
-    scales), rounded where ``prec`` says.
+    scales), rounded where ``prec`` says; ``wire`` marks the directed
+    edges (in ``e``'s order) that cross between fogs, whose senders' rows
+    go through ``wire_round_trip`` where ``prec.halo`` is set.
 
     A row's scale is its norm before SAGE's final L2 normalisation (1 for
     GCN). A row whose norm is near 0 there points anywhere after it, so
     rounding alone turns it round; ``scaled_rms`` weighs each row's error
     by this scale, which compares the rows as they were before the
     normalisation amplified their rounding."""
+    model = spec.model_kind(kind)
     scale = np.ones(e.num_vertices, np.float32)
     h = _round(feats, prec.store)
     deg = e.degree.astype(np.float32)[:, None]
+    split = wire is not None and prec.halo is not None
+    if split:
+        wire = np.asarray(wire, bool)
+        adj = (_adjacency(e, ~wire), _adjacency(e, wire))
     for i, p in enumerate(params):
-        w = np.asarray(p["w"], np.float32)
-        b = np.asarray(p["b"], np.float32)
-        a = _round((e.adj @ _round(h, prec.spmm).astype(np.float64))
-                   .astype(np.float32), prec.store)
-        if kind == "gcn":
-            out = _matmul((a + h) / (deg + 1.0), w, prec.dense) + b
-        elif kind == "sage":
-            f = h.shape[-1]
-            out = (_matmul(a / np.maximum(deg, 1.0), w[:f], prec.dense)
-                   + _matmul(h, w[f:], prec.dense) + b)
-        else:
-            raise ValueError(f"no reference for model kind {kind!r}")
-        if i < len(params) - 1:
-            out = np.maximum(out, 0.0)
-        if kind == "sage":
-            norm = np.sqrt(np.sum(np.square(out.astype(np.float64)), axis=-1,
-                                  keepdims=True))
-            scale = norm[:, 0].astype(np.float32)
-            out = (out / np.maximum(norm, 1e-12)).astype(np.float32)
+        p = {k: np.asarray(v, np.float32) for k, v in p.items()}
+        read = _round(h, prec.spmm)
+        parts = (((adj[0], read),
+                  (adj[1], _round(wire_round_trip(h, prec.halo), prec.spmm)))
+                 if split else ((e.adj, read),))
+        out, s = model.reference_layer(
+            p, Layer(h, deg, parts, prec.store, prec.dense),
+            i == len(params) - 1)
+        if s is not None:
+            scale = s
         h = _round(out, prec.store)
     return h, scale
 

@@ -8,10 +8,16 @@ a configuration or a per-layer metric by adding files and entries:
   * a traffic mix: ``bench/traffic/<traffic>.json``;
   * a per-layer metric: ``bench/metrics/<name>.py``, whose ``read(m)``
     returns the number, or None where the run has nothing to read;
+  * a model kind: ``bench/kinds/<kind>.py``, whose ``init(key, widths)``
+    draws the weights, ``reference_layer(p, x, last)`` is one layer of
+    the plain reference, and ``layer_widths(widths)`` and
+    ``dense_work(v, fi, rows, fo, b)`` give the shapes that the work
+    counts read;
   * the chip's peaks: ``bench/peaks.json``, keyed by ``device_kind``.
 """
 from __future__ import annotations
 
+import functools
 import importlib.util
 import json
 import pathlib
@@ -62,15 +68,31 @@ def load_cell(name: str, root: pathlib.Path = ROOT) -> Cell:
     return Cell(name, int(w["chips"]), config, traffic, e2e, per_layer)
 
 
+@functools.lru_cache(maxsize=64)
+def _module(path: pathlib.Path, name: str):
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
 def metric_reader(name: str, root: pathlib.Path = ROOT
                   ) -> Callable[[object], Optional[float]]:
     """``read`` of ``bench/metrics/<name>.py``."""
     path = pathlib.Path(root) / "bench" / "metrics" / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(
-        "bench_metric_" + name.replace(".", "_").replace("-", "_"), path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return _module(path, "bench_metric_" + name.replace(".", "_")
+                   .replace("-", "_")).read
+
+
+def model_kind(kind: str, root: pathlib.Path = ROOT):
+    """The module ``bench/kinds/<kind>.py``; a kind without one is an
+    error that names the file looked for."""
+    path = pathlib.Path(root) / "bench" / "kinds" / f"{kind}.py"
+    if not path.is_file():
+        raise FileNotFoundError(f"no model kind {kind!r}: {path} does not "
+                                f"exist")
+    return _module(path, "bench_kind_" + kind.replace(".", "_")
+                   .replace("-", "_"))
 
 
 def peaks(device_kind: str, root: pathlib.Path = ROOT) -> dict:

@@ -13,6 +13,8 @@ from typing import Sequence, Tuple
 
 import numpy as np
 
+from bench import spec
+
 
 def spmm(e: int, v_src: int, v_out: int, f: int, b: int,
          halo: int = 0) -> Tuple[float, float]:
@@ -26,10 +28,9 @@ def spmm(e: int, v_src: int, v_out: int, f: int, b: int,
 
 
 def layer_widths(kind: str, widths: Sequence[int]):
-    """(input width, weight rows, output width) of each layer; SAGE's
-    weight stacks the neighbour mean's rows over the vertex's own."""
-    for fi, fo in zip(widths[:-1], widths[1:]):
-        yield fi, (2 * fi if kind == "sage" else fi), fo
+    """(input width, weight rows, output width) of each layer, as the
+    kind's file (``bench/kinds/<kind>.py``) lays out its weights."""
+    return spec.model_kind(kind).layer_widths(widths)
 
 
 def served_spmm(kind: str, widths: Sequence[int], v: int, e: int, b: int,
@@ -46,13 +47,15 @@ def served_spmm(kind: str, widths: Sequence[int], v: int, e: int, b: int,
 def served_model(kind: str, widths: Sequence[int], v: int, e: int, b: int,
                  halo: int = 0) -> Tuple[float, float]:
     """The whole model for one batch: each layer's neighbour-sum and dense
-    update, reading its input rows, the edges and the weights once and
-    writing its output rows once."""
+    update (the kind's ``dense_work``), reading its input rows and the
+    edges once and writing its output rows once."""
+    model = spec.model_kind(kind)
     flops = nbytes = 0.0
     for fi, rows, fo in layer_widths(kind, widths):
-        flops += 2.0 * e * fi * b + 2.0 * v * rows * fo * b
+        dense_flops, dense_bytes = model.dense_work(v, fi, rows, fo, b)
+        flops += 2.0 * e * fi * b + dense_flops
         nbytes += (4.0 * b * v * (fi + fo) + 8.0 * e + b * halo * (fi + 8.0)
-                   + 4.0 * (rows * fo + fo))
+                   + dense_bytes)
     return flops, nbytes
 
 

@@ -75,6 +75,10 @@ def _run(cell, scale, breaker):
     ("siot-gcn.poisson", 0.05, None),
     ("siot-gcn.poisson", 0.05, stale),
     ("siot-gcn.poisson", 0.05, altered),
+    ("siot-gcn.closed16", 0.05, None),
+    ("siot-gcn.closed16", 0.05, stale),
+    ("siot-gcn.closed16", 0.05, half),
+    ("siot-gcn.closed16", 0.05, altered),
 ])
 def test_a_broken_program_reads_incorrect(cell, scale, fault):
     r = _run(cell, scale, fault)
@@ -87,7 +91,12 @@ def test_a_broken_program_reads_incorrect(cell, scale, fault):
         assert r["checks"]["rms_err"]["value"] > r["checks"]["rms_err"]["limit"]
 
 
-def test_halo_exchange_left_out_reads_incorrect():
+@pytest.mark.parametrize("kernels", [False, True])
+def test_halo_exchange_left_out_reads_incorrect(kernels):
+    """On four virtual devices. With ``kernels``, the cell runs as on the
+    chip: the Pallas path (interpret mode), whose halo crosses as uint8
+    codes, checked against the reference with the wire that the TPU's
+    stated precision models."""
     code = textwrap.dedent(f"""
         import json, sys, time
         sys.path[:0] = [{spec.ROOT.as_posix()!r}]
@@ -96,10 +105,16 @@ def test_halo_exchange_left_out_reads_incorrect():
         from repro.runtime import bsp
 
         root = spec.ROOT
+        config = dict(json.loads((root / 'bench/configs/siot-gcn-4fog.json')
+                                 .read_text()), name='siot-gcn-4fog')
+        if {kernels}:
+            config['engine'] = dict(config['engine'], aggregation='pallas')
+            rounding = config['precision']['rounding']
+            config['precision'] = dict(config['precision'], rounding=dict(
+                rounding, cpu=[{{'halo': r['halo']}}
+                               for r in rounding['tpu']][:1]))
         cell = spec.Cell(
-            'siot-gcn-4fog.closed16', 4,
-            dict(json.loads((root / 'bench/configs/siot-gcn-4fog.json')
-                            .read_text()), name='siot-gcn-4fog'),
+            'siot-gcn-4fog.closed16', 4, config,
             dict(json.loads((root / 'bench/traffic/closed16.json')
                             .read_text()), name='closed16'), [], [])
 

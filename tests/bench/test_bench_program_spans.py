@@ -60,8 +60,9 @@ def test_idle_goes_to_the_innermost_span_by_path():
 def test_served_cell_traces_every_span(tmp_path, cell, scale):
     """A traced run of the cell at a small scale on the CPU: every span
     the program declares is there, once per batch or per request, the
-    codec's stages sit under both the collect and the pricing, and the
-    upload carries at least every request's feature table."""
+    codec's quantize sits under both the collect and the pricing and its
+    lossless stage under the pricing alone, and the upload carries at
+    least every request's feature table."""
     c = spec.load_cell(cell)
     r = harness.run_cell(c, SEED, 1.5, True, devices=jax.devices(),
                          t_start=time.perf_counter(), scale=scale,
@@ -83,8 +84,9 @@ def test_served_cell_traces_every_span(tmp_path, cell, scale):
     for stage in ("execute.dispatch", "execute.wait", "execute.download"):
         assert tot[f"{top}/execute/{stage}"].count >= drains
     for parent in ("collect", "server.price"):
-        for stage in ("daq.quantize", "daq.lossless"):
-            assert f"{top}/{parent}/{stage}" in tot
+        assert f"{top}/{parent}/daq.quantize" in tot
+    assert f"{top}/server.price/daq.lossless" in tot
+    assert f"{top}/collect/daq.lossless" not in tot
     assert tot[top + "/collect/daq.dequantize"].count == requests
 
     # Inside and outside views of the same calls agree.
@@ -99,6 +101,7 @@ def test_served_cell_traces_every_span(tmp_path, cell, scale):
     assert upload >= requests * v * f * 4
 
     nums = program_spans.per_layer(tot)
+    assert nums.pop("lossless_ms") is None   # no lossless stage in collect
     assert all(x is not None and x > 0 for x in nums.values()), nums
     assert nums["upload_mb"] * drains * 1e6 == pytest.approx(upload)
 
@@ -109,7 +112,7 @@ def test_served_cell_traces_every_span(tmp_path, cell, scale):
     red = program_spans.reduce({0: waits}, bench_spans, spans, window)
     assert sum(red.idle_by_span.values()) == pytest.approx(
         red.window_s - red.busy_s[0])
-    assert top + "/collect/daq.lossless" in red.idle_by_span
+    assert top + "/server.price/daq.lossless" in red.idle_by_span
 
 
 def test_recorded_chip_trace_with_program_spans():

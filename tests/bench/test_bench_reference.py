@@ -94,7 +94,8 @@ def test_stated_rounding_is_bfloat16_operands():
     assert reference._round(np.float32(1.0 + 2.0**-9), reference.BF16) == 1.0
 
 
-CONTROL_CASES = [("siot-gcn", 0.25), ("yelp-sage", 1.0)]
+CONTROL_CASES = [("siot-gcn", 0.25), ("yelp-sage", 1.0),
+                 ("siot-gcn-4fog", 0.25)]
 
 
 @pytest.mark.parametrize("config, scale", CONTROL_CASES)
@@ -103,7 +104,9 @@ def test_bfloat16_control_fails_the_limit(config, scale):
     place) fails the configuration's limit against the nearest rounding
     of the stated TPU precision on every seed, at a size a test run
     holds; the program on the CPU reads far below it against the CPU's
-    stated precision."""
+    stated precision. Where the stated precision sends halo rows over a
+    wire, both the references and the control carry it, over the edges
+    that the configuration's placement splits between fogs."""
     from repro.api import Engine
     from repro.gnn.graph import from_edge_list
 
@@ -122,8 +125,18 @@ def test_bfloat16_control_fails_the_limit(config, scale):
         x = reference.daq(traffic.upload_pool(raw.features, 1,
                                               cfg["uploads"], seed)[0],
                           e.degree)
-        refs = [reference.forward(kind, params_np, e, x, p) for p in tpu]
-        ctl, _ = reference.forward(kind, params_np, e, x, reference.CONTROL)
+        wire = None
+        if tpu[0].halo is not None:
+            a = np.asarray(Engine((params, kind), **dict(
+                cfg["engine"], executor="single")).compile(g)
+                .placement.assignment)
+            wire = a[e.senders] != a[e.receivers]
+            assert wire.mean() > 0.1
+        refs = [reference.forward(kind, params_np, e, x, p, wire)
+                for p in tpu]
+        ctl, _ = reference.forward(
+            kind, params_np, e, x,
+            reference.CONTROL._replace(halo=tpu[0].halo), wire)
         assert min(reference.scaled_rms(ctl, *r) for r in refs) > limit, seed
         sess = Engine((params, kind), compressor="none",
                       executor="single").compile(g).session()
