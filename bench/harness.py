@@ -65,15 +65,16 @@ def use_compile_cache(root) -> str:
     return path
 
 
-def make_params(kind: str, widths: List[int], seed: int):
-    """The kind's weights (``init`` of ``bench/kinds/<kind>.py``), made on
-    the device in one jitted call from the seed."""
+def make_params(kind: str, widths: List[int], seed: int, **keys):
+    """The kind's weights (``init`` of ``bench/kinds/<kind>.py``, given the
+    configuration's other model ``keys``), made on the device in one
+    jitted call from the seed."""
     import jax
 
     model = spec.model_kind(kind)
     key = jax.random.fold_in(jax.random.PRNGKey(seed & 0xFFFFFFFF),
                              seed >> 32)
-    return jax.jit(lambda k: model.init(k, widths))(key)
+    return jax.jit(lambda k: model.init(k, widths, **keys))(key)
 
 
 class CompileCounter:
@@ -116,11 +117,12 @@ def build(cell: spec.Cell, seed: int, scale: Optional[float] = None):
     raw = graphs.make(cfg["dataset"], cfg["scale"] if scale is None
                       else scale, cfg["graph_seed"])
     widths = list(model["widths"])
+    keys = spec.model_keys(model)
     if widths[0] != raw.features.shape[1]:
         raise ValueError(f"{cfg['name']}: input width {widths[0]} != "
                          f"feature width {raw.features.shape[1]}")
     graph = from_edge_list(raw.num_vertices, raw.edges, raw.features)
-    params = make_params(model["kind"], widths, seed)
+    params = make_params(model["kind"], widths, seed, **keys)
     plan = Engine((params, model["kind"]), cluster=eng["cluster"],
                   executor=eng["executor"], aggregation=eng["aggregation"],
                   compressor=eng["compressor"],
@@ -136,9 +138,9 @@ def build(cell: spec.Cell, seed: int, scale: Optional[float] = None):
     halo = (work.halo_pairs(edges.senders, edges.receivers, assign)
             if plan.num_fogs > 1 and eng["executor"] == "mesh-bsp" else 0)
     return SimpleNamespace(
-        kind=model["kind"], widths=widths, params=params, server=server,
-        plan=plan, pool=pool, edges=edges, assign=assign, halo=halo,
-        v=raw.num_vertices, e=len(edges.senders),
+        kind=model["kind"], widths=widths, model_keys=keys, params=params,
+        server=server, plan=plan, pool=pool, edges=edges, assign=assign,
+        halo=halo, v=raw.num_vertices, e=len(edges.senders),
         max_batch=int(cfg["max_batch"]))
 
 

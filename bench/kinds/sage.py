@@ -11,17 +11,13 @@ from bench import dense
 
 
 def layer_widths(widths):
-    """(input width, weight rows, output width) of each layer."""
-    for fi, fo in zip(widths[:-1], widths[1:]):
-        yield fi, 2 * fi, fo
+    """Each layer as the work counts read it (``dense.Shape``)."""
+    return [dense.Shape(fi, 2 * fi, fo, fi)
+            for fi, fo in zip(widths[:-1], widths[1:])]
 
 
 def init(key, widths):
     return dense.glorot_layers(key, layer_widths(widths))
-
-
-def dense_work(v, fi, rows, fo, b):
-    return dense.update_work(v, rows, fo, b)
 
 
 def reference_layer(p, x, last):

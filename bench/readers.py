@@ -3,8 +3,9 @@
 ``m`` is the run as the harness hands it to ``bench/metrics/<name>.py``:
 ``m.spans`` (host spans, ``bench/spans.py``), ``m.trace`` (the device
 trace's reduction, ``bench/trace.py``), ``m.run`` (the loop's batches),
-``m.b`` (the built cell: model kind, widths, vertices, edges, halo rows,
-and ``sizes``, the batch size of each Response as the Server counted it),
+``m.b`` (the built cell: model kind, widths and other model keys,
+vertices, edges, halo rows, and ``sizes``, the batch size of each
+Response as the Server counted it),
 ``m.peak`` (the chip's peaks) and ``m.chips``. Each function returns None
 where the run has nothing to read.
 """
@@ -47,14 +48,16 @@ def device_idle(m) -> Optional[float]:
 
 
 def _least(m, count) -> float:
-    """Least seconds of the traced batches' work, one layer at a time."""
+    """Least seconds of the traced batches' work, one layer at a time, each
+    layer as its model kind describes it."""
     b = m.b
+    layers = work.layer_widths(b.kind, b.widths, **b.model_keys)
     total = 0.0
     for batch in m.run.batches:
         n = len(batch.ids)
-        for fi, rows, fo in work.layer_widths(b.kind, b.widths):
+        for layer in layers:
             total += work.least_seconds(
-                *count(b.kind, [fi, fo], b.v, b.e, n, b.halo), m.peak)
+                *count([layer], b.v, b.e, n, b.halo), m.peak)
     return total
 
 

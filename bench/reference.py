@@ -9,14 +9,16 @@ the benchmark's weights and one request's upload it computes:
   * the GNN forward of the paper's Table I:
       GCN   h_v' = act(((sum_{u->v} h_u + h_v) / (deg_v + 1)) W + b)
       SAGE  h_v' = l2norm(act(mean_{u->v} h_u W[:F] + h_v W[F:] + b))
-    with ReLU between layers and no activation after the last, in float32
-    with exact sums, rounded where a ``Precision`` says.
+    with ReLU between layers and no activation after the last, and
+      GAT   h_v' = ELU(concat_k sum_{u in N(v)+v} a_vu^k h_u W_k)
+    with its heads averaged and no activation in the last layer, all in
+    float32 with exact sums, rounded where a ``Precision`` says.
 
 The check compares the program with this forward at the precision its
 configuration states on the platform (``stated``): on a TPU, XLA's default
 precision rounds both operands of a float32 dense matmul to bfloat16 and
-accumulates in float32, and the Pallas SpMM may round its feature operand
-so too; an answer is held to the nearer of those two roundings.
+accumulates in float32, and the Pallas SpMM may round its operands so
+too; an answer is held to the nearer of those two roundings.
 ``CONTROL`` is the forward one precision lower, as a program that keeps
 its features and activations in bfloat16 would compute it on the chip:
 every array stored in bfloat16, every matmul on bfloat16 operands with
@@ -29,8 +31,9 @@ different fogs. Over such an edge the receiver reads the sender's row
 after a per-row round trip, all in float32: over the layer's input width,
 ``lo = min``, ``step = max(max - lo, 1e-12) / 255``, codes
 ``clip(rint((h - lo) / step), 0, 255)``, value ``code * step + lo``. The
-SpMM's rounding then applies to that value as to any feature operand.
-Local edges and the vertex's own row read the exact row.
+layer then reads that value where it would read the exact row (GCN and
+SAGE: as the SpMM's feature operand, with its rounding). Local edges and
+the vertex's own row read the exact row.
 
 Each model kind's layer lives in ``bench/kinds/<kind>.py``
 (``reference_layer``), found by name as ``bench/spec.py`` says.
@@ -96,7 +99,8 @@ class Precision(NamedTuple):
 
     ``store``: every array kept between operations (the upload, each
     neighbour-sum, each layer's output); ``dense``: both operands of the
-    dense matmuls; ``spmm``: the feature operand of the neighbour-sum;
+    dense matmuls; ``spmm``: the operands of the neighbour-sum (the rows
+    it sums and, where a kind weighs its edges, the weights);
     ``halo``: the integer codes of the rows that cross between fogs.
     Element-wise arithmetic runs in float32 and sums accumulate exactly,
     then round to float32, as the chip's matrix unit accumulates."""
@@ -145,26 +149,38 @@ def wire_round_trip(h: np.ndarray, codes: np.dtype) -> np.ndarray:
 class Layer(NamedTuple):
     """What one layer of the reference reads: a kind's ``reference_layer``
     gets it. ``parts`` pairs each adjacency ([receiver, sender] ones) with
-    the rows its senders are read as: one pair, or the local edges with
-    the exact rows and the wire's edges with the round-tripped ones, each
-    rounded as the SpMM's feature operand."""
+    the rows its senders are read as, unrounded: one pair, or the local
+    edges with the exact rows (``h`` itself) and the wire's edges with
+    the round-tripped ones. ``spmm`` is the rounding of the
+    neighbour-sum's operands: ``neighbour_sum`` applies it to those rows,
+    and a kind that sums rows it computes itself applies it to both of
+    its sum's operands (``spmm_operand``)."""
     h: np.ndarray               # [V, F] input rows as stored
     deg: np.ndarray             # [V, 1] float32 in-degree
     parts: Tuple[Tuple[scipy.sparse.csr_matrix, np.ndarray], ...]
     store: Optional[np.dtype]
     dense: Optional[np.dtype]
+    spmm: Optional[np.dtype]
 
     def neighbour_sum(self) -> np.ndarray:
-        """[V, F] sum over u->v of the sender's row, summed exactly, then
-        rounded as stored."""
+        """[V, F] sum over u->v of the sender's row, rounded as the SpMM's
+        operand, summed exactly, then rounded as stored."""
         (adj, rows), *rest = self.parts
-        a = adj @ rows.astype(np.float64)
+        a = adj @ self.spmm_operand(rows).astype(np.float64)
         for adj, rows in rest:
-            a = a + adj @ rows.astype(np.float64)
-        return _round(a.astype(np.float32), self.store)
+            a = a + adj @ self.spmm_operand(rows).astype(np.float64)
+        return self.stored(a.astype(np.float32))
 
     def matmul(self, x: np.ndarray, w: np.ndarray) -> np.ndarray:
         return _matmul(x, w, self.dense)
+
+    def stored(self, x: np.ndarray) -> np.ndarray:
+        """``x`` as kept between operations."""
+        return _round(x, self.store)
+
+    def spmm_operand(self, x: np.ndarray) -> np.ndarray:
+        """``x`` as an operand of a neighbour-sum."""
+        return _round(x, self.spmm)
 
 
 def _adjacency(e: Edges, keep: np.ndarray) -> scipy.sparse.csr_matrix:
@@ -197,12 +213,10 @@ def forward(kind: str, params: Sequence[dict], e: Edges, feats: np.ndarray,
         adj = (_adjacency(e, ~wire), _adjacency(e, wire))
     for i, p in enumerate(params):
         p = {k: np.asarray(v, np.float32) for k, v in p.items()}
-        read = _round(h, prec.spmm)
-        parts = (((adj[0], read),
-                  (adj[1], _round(wire_round_trip(h, prec.halo), prec.spmm)))
-                 if split else ((e.adj, read),))
+        parts = (((adj[0], h), (adj[1], wire_round_trip(h, prec.halo)))
+                 if split else ((e.adj, h),))
         out, s = model.reference_layer(
-            p, Layer(h, deg, parts, prec.store, prec.dense),
+            p, Layer(h, deg, parts, prec.store, prec.dense, prec.spmm),
             i == len(params) - 1)
         if s is not None:
             scale = s

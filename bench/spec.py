@@ -8,11 +8,19 @@ a configuration or a per-layer metric by adding files and entries:
   * a traffic mix: ``bench/traffic/<traffic>.json``;
   * a per-layer metric: ``bench/metrics/<name>.py``, whose ``read(m)``
     returns the number, or None where the run has nothing to read;
-  * a model kind: ``bench/kinds/<kind>.py``, whose ``init(key, widths)``
-    draws the weights, ``reference_layer(p, x, last)`` is one layer of
-    the plain reference, and ``layer_widths(widths)`` and
-    ``dense_work(v, fi, rows, fo, b)`` give the shapes that the work
-    counts read;
+  * a model kind: ``bench/kinds/<kind>.py``, the configuration's
+    ``model.kind``. Its other model keys besides ``widths`` (``keys``,
+    such as GAT's ``heads`` and ``skip``) reach the kind as keywords;
+    a kind takes only the keys it knows. ``init(key, widths, **keys)``
+    draws the weights. ``layer_widths(widths, **keys)`` describes each
+    layer for the work counts (``bench/work.py``): ``fi`` and ``fo``,
+    the input and output widths; ``sum_width``, the columns its
+    neighbour-sum runs over; ``self_loops``, whether that sum also reads
+    every vertex's own row (e + v edges); and ``dense_work(v, e, b)``,
+    the FLOPs and bytes of the rest of the layer. ``reference_layer(p,
+    x, last)`` is one layer of the plain reference over ``x``
+    (``reference.Layer``), reading the layer's structure from its
+    weights ``p``;
   * the chip's peaks: ``bench/peaks.json``, keyed by ``device_kind``.
 """
 from __future__ import annotations
@@ -66,6 +74,11 @@ def load_cell(name: str, root: pathlib.Path = ROOT) -> Cell:
     per_layer = [m for m in bench["per_layer"]
                  if m["moves"] in moved and _reported(m, name)]
     return Cell(name, int(w["chips"]), config, traffic, e2e, per_layer)
+
+
+def model_keys(model: dict) -> dict:
+    """A configuration's model keys that its kind takes as keywords."""
+    return {k: v for k, v in model.items() if k not in ("kind", "widths")}
 
 
 @functools.lru_cache(maxsize=64)

@@ -1,7 +1,8 @@
 """The least work a served batch needs, from the configuration's shapes and
 the graph's edges alone, never from how the program lays them out.
 
-Counts are per batch of ``b`` requests over a graph of ``v`` vertices and
+Each layer's shapes come from its model kind (``layer_widths``). Counts
+are per batch of ``b`` requests over a graph of ``v`` vertices and
 ``e`` directed edges. ``halo`` is the number of (vertex, other fog) pairs
 in which a fog reads a vertex that another fog owns (0 on one chip); those
 rows cross between chips as uint8 codes with 8 bytes of (scale, min) per
@@ -27,35 +28,45 @@ def spmm(e: int, v_src: int, v_out: int, f: int, b: int,
     return flops, nbytes
 
 
-def layer_widths(kind: str, widths: Sequence[int]):
-    """(input width, weight rows, output width) of each layer, as the
-    kind's file (``bench/kinds/<kind>.py``) lays out its weights."""
-    return spec.model_kind(kind).layer_widths(widths)
+def layer_widths(kind: str, widths: Sequence[int], **keys) -> list:
+    """Each layer as the kind's file (``bench/kinds/<kind>.py``) describes
+    it: input width ``fi``, output width ``fo``, the columns its
+    neighbour-sum runs over (``sum_width``), whether that sum also reads
+    every vertex's own row (``self_loops``), and ``dense_work(v, e, b)``,
+    the rest of the layer. ``keys`` are the configuration's model keys
+    past ``kind`` and ``widths``."""
+    return list(spec.model_kind(kind).layer_widths(widths, **keys))
 
 
-def served_spmm(kind: str, widths: Sequence[int], v: int, e: int, b: int,
+def _sum_edges(layer, v: int, e: int) -> int:
+    return e + v if layer.self_loops else e
+
+
+def served_spmm(layers, v: int, e: int, b: int,
                 halo: int = 0) -> Tuple[float, float]:
-    """The neighbour-sums of one batch through every layer."""
+    """The neighbour-sums of one batch through ``layers`` (``layer_widths``
+    of the model), each over its own width and edges."""
     flops = nbytes = 0.0
-    for fi, _, _ in layer_widths(kind, widths):
-        f, n = spmm(e, v, v, fi, b, halo)
+    for layer in layers:
+        f, n = spmm(_sum_edges(layer, v, e), v, v, layer.sum_width, b, halo)
         flops += f
         nbytes += n
     return flops, nbytes
 
 
-def served_model(kind: str, widths: Sequence[int], v: int, e: int, b: int,
+def served_model(layers, v: int, e: int, b: int,
                  halo: int = 0) -> Tuple[float, float]:
-    """The whole model for one batch: each layer's neighbour-sum and dense
-    update (the kind's ``dense_work``), reading its input rows and the
-    edges once and writing its output rows once."""
-    model = spec.model_kind(kind)
+    """The whole model for one batch through ``layers``: each layer's
+    neighbour-sum and the rest of it (its ``dense_work``), reading its
+    input rows and the edges once and writing its output rows once."""
     flops = nbytes = 0.0
-    for fi, rows, fo in layer_widths(kind, widths):
-        dense_flops, dense_bytes = model.dense_work(v, fi, rows, fo, b)
-        flops += 2.0 * e * fi * b + dense_flops
-        nbytes += (4.0 * b * v * (fi + fo) + 8.0 * e + b * halo * (fi + 8.0)
-                   + dense_bytes)
+    for layer in layers:
+        fi, fo = layer.fi, layer.fo
+        edges = _sum_edges(layer, v, e)
+        dense_flops, dense_bytes = layer.dense_work(v, e, b)
+        flops += 2.0 * edges * layer.sum_width * b + dense_flops
+        nbytes += (4.0 * b * v * (fi + fo) + 8.0 * edges
+                   + b * halo * (fi + 8.0) + dense_bytes)
     return flops, nbytes
 
 

@@ -5,7 +5,9 @@ scale on the CPU, with one fault planted in the served program: an answer
 that is the previous batch's (state left unchanged), half of the batch
 left out, an answer altered where it is produced, two batch slots
 swapped, and, on four virtual devices, the halo exchange between chips
-left out. The same run without a fault reads correct."""
+left out. The same run without a fault reads correct, on a GAT cell held
+in memory as on the cells of ``BENCHMARK.json``."""
+import json
 import os
 import subprocess
 import sys
@@ -89,6 +91,30 @@ def test_a_broken_program_reads_incorrect(cell, scale, fault):
     else:
         assert not r["correct"] and r["failed"] > 0, r["checks"]
         assert r["checks"]["rms_err"]["value"] > r["checks"]["rms_err"]["limit"]
+
+
+@pytest.mark.parametrize("fault", [None, altered])
+def test_a_gat_cell_reads_correct_until_broken(fault):
+    """A GAT cell held in memory, as a later configuration would add one:
+    SIoT's configuration with a two-layer, one-head GAT, which the
+    program serves on its segment-sum path."""
+    root = spec.ROOT
+    cfg = json.loads((root / "bench/configs/siot-gcn.json").read_text())
+    config = dict(cfg, name="siot-gat",
+                  model={"kind": "gat", "widths": [52, 16, 2]})
+    cell = spec.Cell("siot-gat.closed16", 1, config, dict(
+        json.loads((root / "bench/traffic/closed16.json").read_text()),
+        name="closed16"), [], [])
+    r = harness.run_cell(cell, SEED, 1.5, False, devices=jax.devices(),
+                         t_start=time.perf_counter(), scale=0.05,
+                         breaker=fault, compile_cache=False)
+    assert r["attempted"] > 0
+    assert r["checks"]["unanswered"]["value"] == 0
+    if fault is None:
+        assert r["correct"] and r["failed"] == 0, r["checks"]
+        assert r["checks"]["rms_err"]["value"] < 1e-6, r["checks"]
+    else:
+        assert not r["correct"] and r["failed"] > 0, r["checks"]
 
 
 @pytest.mark.parametrize("kernels", [False, True])

@@ -20,7 +20,8 @@ def _setup(dataset, kind, scale, seed):
     return raw, g, params, params_np
 
 
-@pytest.mark.parametrize("dataset, kind", [("siot", "gcn"), ("yelp", "sage")])
+@pytest.mark.parametrize("dataset, kind", [("siot", "gcn"), ("yelp", "sage"),
+                                           ("siot", "gat")])
 def test_reference_matches_gnn_apply(dataset, kind):
     from repro.gnn import models
     from repro.gnn.layers import EdgeList
@@ -36,10 +37,10 @@ def test_reference_matches_gnn_apply(dataset, kind):
     want = np.asarray(models.gnn_apply(params, kind, x, EdgeList.from_graph(g)))
     got, scale = reference.forward(kind, params_np, e, x)
     assert reference.scaled_rms(got, want, scale) < 1e-6
-    if kind == "gcn":
-        assert np.all(scale == 1.0)
-    else:
+    if kind == "sage":
         assert np.allclose(np.linalg.norm(got, axis=-1), 1.0, atol=1e-5)
+    else:
+        assert np.all(scale == 1.0)
 
 
 @pytest.mark.parametrize("dataset", ["siot", "yelp"])
